@@ -113,6 +113,9 @@ def main(argv: list[str] | None = None) -> int:
                     help="run a traced 2-round smoke federation and write its "
                          "Chrome trace-event JSON here (open in Perfetto)")
     args = ap.parse_args(argv)
+    from repro.utils.jax_env import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.only:
         unknown = set(args.only.split(",")) - set(SUITES)

@@ -148,20 +148,15 @@ def _legacy_quantize(value, fmt):
     per tensor (the new path fuses these into one async dispatch and
     blocks once per message)."""
     import jax
-    import jax.numpy as jnp
 
     from repro.core.quantization import QuantizedTensor
     from repro.kernels import ops
 
     arr = np.asarray(value)
     if fmt in ("fp4", "nf4"):
-        x2d, _ = ops._pad_to_blocks(
-            jnp.asarray(arr).reshape(-1).astype(jnp.float32), ops.BLOCK4)
-        payload, absmax = ops._REF_Q4[fmt](x2d)
+        payload, absmax = ops._REF_Q4[fmt](ops._flat_blocks(arr, ops.BLOCK4))
     elif fmt == "blockwise8":
-        x2d, _ = ops._pad_to_blocks(
-            jnp.asarray(arr).reshape(-1).astype(jnp.float32), ops.BLOCK8)
-        payload, absmax = ops._REF_Q8(x2d)
+        payload, absmax = ops._REF_Q8(ops._flat_blocks(arr, ops.BLOCK8))
     else:
         raise ValueError(fmt)
     jax.block_until_ready((payload, absmax))  # the per-item sync

@@ -111,16 +111,26 @@ def quantize_state_dict(sd: Mapping[str, jnp.ndarray], fmt: str) -> dict[str, Qu
 _BLOCK_OF = {"blockwise8": 4096, "fp4": 64, "nf4": 64}
 
 
+#: elements per kernel dispatch when a fused group is larger: a whole
+#: model in one dispatch needs its fp32 input plus the kernel's padded
+#: working copies on the device at once (~10 GB for a 0.6B-parameter
+#: model, most of a 16 GB TPU), while a bounded slice keeps the device
+#: footprint O(slice). A multiple of every format's block size times its
+#: kernel's grid rows, so slice boundaries never split a block and each
+#: slice is padded the same way the whole group would be.
+GROUP_SLICE_ELEMS = 1 << 26
+
+
 def _fused_quantize_group(
     items: Mapping[str, Any], names: list[str], fmt: str
 ) -> dict[str, QuantizedTensor]:
-    """One kernel dispatch for a whole format group: every tensor is
-    padded to whole quant blocks (exactly the per-tensor wire layout)
-    and laid back to back in one fp32 buffer, the blocked kernel runs
-    once over all of it, and each tensor's payload/absmax are row
-    slices of the single result. Block boundaries never span tensors,
-    so the sliced payloads are bitwise-identical to quantizing each
-    tensor alone.
+    """One kernel dispatch per :data:`GROUP_SLICE_ELEMS` of a whole
+    format group: every tensor is padded to whole quant blocks (exactly
+    the per-tensor wire layout) and laid back to back in one fp32
+    buffer, the blocked kernel runs over it slice by slice, and each
+    tensor's payload/absmax are row slices of the joined result. Block
+    boundaries never span tensors or slices, so the sliced payloads are
+    bitwise-identical to quantizing each tensor alone.
 
     The concat buffer is O(group) *compute scratch* on the sender —
     the same order as the fp32 message the sender already holds, and
@@ -138,11 +148,17 @@ def _fused_quantize_group(
     for _name, arr, start, _nb in spans:
         flat = np.ascontiguousarray(arr).reshape(-1)
         big[start * block: start * block + flat.size] = flat
-    if fmt == "blockwise8":
-        q, am = ops.quantize_blockwise8(big)
-    else:
-        q, am = ops.quantize_4bit(big, fmt)
-    q_np, am_np = np.asarray(q), np.asarray(am)   # the one sync point
+    qs, ams = [], []
+    for lo in range(0, big.size, GROUP_SLICE_ELEMS):
+        part = big[lo:lo + GROUP_SLICE_ELEMS]
+        if fmt == "blockwise8":
+            q, am = ops.quantize_blockwise8(part)
+        else:
+            q, am = ops.quantize_4bit(part, fmt)
+        qs.append(np.asarray(q))     # one sync per slice
+        ams.append(np.asarray(am))
+    q_np = qs[0] if len(qs) == 1 else np.concatenate(qs)
+    am_np = ams[0] if len(ams) == 1 else np.concatenate(ams)
     return {
         name: QuantizedTensor(q_np[start:start + nb], am_np[start:start + nb],
                               fmt, tuple(arr.shape), arr.dtype)
@@ -154,8 +170,9 @@ def quantize_batch(
     items: Mapping[str, Any], fmt_for: Mapping[str, str]
 ) -> dict[str, QuantizedTensor]:
     """Whole-message quantization: one kernel dispatch **per format
-    group** (all same-format tensors concatenated block-aligned), one
-    device sync per message.
+    group** (all same-format tensors concatenated block-aligned; groups
+    past :data:`GROUP_SLICE_ELEMS` dispatch once per slice), one device
+    sync per dispatch.
 
     This is the wire hot path's replacement for per-tensor
     dispatch-then-sync inside the streamer loop: serializing item k
@@ -194,9 +211,9 @@ def _fused_dequantize_group(
 ) -> dict[str, np.ndarray]:
     """Inverse of :func:`_fused_quantize_group`: payload/absmax rows of
     every same-format tensor are laid back to back and the blocked
-    kernel runs once over the whole group. Block boundaries never span
-    tensors, so the per-tensor slices are element-wise identical to
-    dequantizing each tensor alone."""
+    kernel runs over them one :data:`GROUP_SLICE_ELEMS` slice at a time.
+    Block boundaries never span tensors, so the per-tensor slices are
+    element-wise identical to dequantizing each tensor alone."""
     block = _BLOCK_OF[fmt]
     spans: list[tuple[str, QuantizedTensor, int, int]] = []  # name, qt, start, nblocks
     total = 0
@@ -207,11 +224,18 @@ def _fused_dequantize_group(
         total += nb
     q_cat = np.concatenate([np.asarray(qt.payload) for _n, qt, _s, _nb in spans])
     am_cat = np.concatenate([np.asarray(qt.absmax) for _n, qt, _s, _nb in spans])
-    if fmt == "blockwise8":
-        flat = ops.dequantize_blockwise8(q_cat, am_cat, (total * block,), np.float32)
-    else:
-        flat = ops.dequantize_4bit(q_cat, am_cat, fmt, (total * block,), np.float32)
-    flat_np = np.asarray(flat)   # the one sync point
+    rows = GROUP_SLICE_ELEMS // block
+    parts = []
+    for lo in range(0, total, rows):
+        n = min(rows, total - lo)
+        if fmt == "blockwise8":
+            flat = ops.dequantize_blockwise8(q_cat[lo:lo + n], am_cat[lo:lo + n],
+                                             (n * block,), np.float32)
+        else:
+            flat = ops.dequantize_4bit(q_cat[lo:lo + n], am_cat[lo:lo + n], fmt,
+                                       (n * block,), np.float32)
+        parts.append(np.asarray(flat))   # one sync per slice
+    flat_np = parts[0] if len(parts) == 1 else np.concatenate(parts)
     out: dict[str, np.ndarray] = {}
     for name, qt, start, _nb in spans:
         size = int(np.prod(qt.orig_shape)) if qt.orig_shape else 1
@@ -225,7 +249,8 @@ def _fused_dequantize_group(
 
 def dequantize_batch(items: Mapping[str, Any]) -> dict[str, Any]:
     """Whole-message dequantization: one kernel dispatch **per format
-    group**, one device sync per group — the receive-side mirror of
+    group** (per :data:`GROUP_SLICE_ELEMS` slice of it), one device sync
+    per dispatch — the receive-side mirror of
     :func:`quantize_batch`. Items that are not :class:`QuantizedTensor`
     (dense arrays, other wire kinds) pass through untouched; cast
     formats (fp32/fp16/bf16) are cheap per-tensor host work. Results

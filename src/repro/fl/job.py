@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import json
 from typing import Any, Optional
 
@@ -76,6 +77,7 @@ from repro.fl.executor import TrainExecutor
 from repro.fl.simulator import FLSimulator, SimulationConfig
 from repro.models import create_model
 from repro.optim import adamw_init, adamw_update
+from repro.utils.jax_env import enable_compile_cache
 from repro.utils.trees import flatten_state_dict, unflatten_state_dict
 
 DEFAULTS: dict[str, Any] = {
@@ -293,7 +295,11 @@ def _client_datasets(spec: dict[str, Any], cfg: Any) -> list[Any]:
 
 
 def _jit_local_step(model: Any, lr: float):
-    @jax.jit
+    # params and optimizer state are donated: train_fn rebinds both every
+    # step, and without aliasing a full-width step holds two copies of
+    # weights + AdamW moments (16.1 GB for qwen1.5-0.5b at 4x512, more
+    # than a 16 GB TPU v5e has)
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def local_step(params, opt, batch):
         (loss, _), grads = jax.value_and_grad(model.loss, has_aux=True)(params, batch)
         params, opt, _ = adamw_update(params, grads, opt, jnp.float32(lr))
@@ -307,8 +313,12 @@ def _train_executor(
     history: Optional[list[float]] = None,
 ) -> TrainExecutor:
     def train_fn(flat_params, rnd):
+        # the task payload is consumed: device arrays the downlink decode
+        # produced become the step's parameters without a copy, and the
+        # first (donating) step frees them — a full-width client does not
+        # hold the received weights beside its training state
         p = unflatten_state_dict(
-            {k: jnp.asarray(np.asarray(v)) for k, v in flat_params.items()}
+            {k: jnp.asarray(v) for k, v in flat_params.items()}
         )
         opt = adamw_init(p)
         loss = None
@@ -356,8 +366,16 @@ def initial_weights(spec: dict[str, Any]) -> dict[str, Any]:
     :func:`build_job` hands the simulator."""
     spec = normalize_spec(spec)
     cfg = get_smoke_config(spec["arch"]) if spec["smoke"] else get_config(spec["arch"])
-    model = create_model(cfg)
-    return flatten_state_dict(model.init(jax.random.PRNGKey(spec["seed"])))
+    return _host_init(create_model(cfg), spec["seed"])
+
+
+def _host_init(model: Any, seed: int) -> dict[str, np.ndarray]:
+    """Seeded round-0 weights as a flat dict of host arrays: the server
+    keeps global weights on the host in every later round (aggregators
+    return NumPy), so round 0 does too, and the device stays free for
+    the clients' training state."""
+    flat = flatten_state_dict(model.init(jax.random.PRNGKey(seed)))
+    return {k: np.asarray(v) for k, v in flat.items()}
 
 
 def _build_filters(spec: dict[str, Any], network: Optional[Any] = None):
@@ -540,8 +558,7 @@ def build_job(spec: dict[str, Any]) -> Job:
         **wire_kwargs,
         **runtime_kwargs,
     )
-    init = flatten_state_dict(model.init(jax.random.PRNGKey(spec["seed"])))
-    return Job(spec, sim, init, history, adaptive)
+    return Job(spec, sim, _host_init(model, spec["seed"]), history, adaptive)
 
 
 def run_job(spec: dict[str, Any]) -> dict[str, Any]:
@@ -569,6 +586,7 @@ def main(argv: Optional[list[str]] = None) -> int:
                     help="record a dual-clock span trace and write Chrome "
                          "trace-event JSON here (open in Perfetto)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     with open(args.spec) as fh:
         spec = json.load(fh)
     if args.trace:

@@ -49,8 +49,13 @@ def block_until_ready(values) -> None:
 
 
 def _flat_blocks(x: jnp.ndarray, block: int) -> jnp.ndarray:
-    """Traced flatten + fp32 cast + zero-pad to whole blocks (inside
-    jit, so the whole chain is one fused executable per input shape)."""
+    """Flatten + fp32 cast + zero-pad to whole quant blocks (traced inside
+    each op's jit, so the chain is one fused executable per input shape).
+
+    Wire-format padding is one block max (<=16 KiB for int8, <=256 B for
+    4-bit); the Pallas paths pad *rows* to their grid granularity
+    (:func:`_pad_rows`) and slice the result back, so grid alignment
+    never inflates the transmitted message."""
     flat = jnp.asarray(x).reshape(-1).astype(jnp.float32)
     n = flat.shape[0]
     padded = int(np.ceil(n / block)) * block
@@ -138,21 +143,6 @@ def backend(name: str):
         _backend = prev
 
 
-def _pad_to_blocks(flat: jnp.ndarray, block: int) -> tuple[jnp.ndarray, int]:
-    """Pad a flat fp32 vector to a whole number of quant blocks.
-
-    Wire-format padding is one block max (<=16 KiB for int8, <=256 B for
-    4-bit); the Pallas wrappers pad *rows* to their grid granularity
-    internally and slice the result back, so grid alignment never inflates
-    the transmitted message.
-    """
-    n = flat.shape[0]
-    padded = int(np.ceil(n / block)) * block
-    if padded != n:
-        flat = jnp.pad(flat, (0, padded - n))
-    return flat.reshape(padded // block, block), n
-
-
 def _pad_rows(x2d: jnp.ndarray, row_multiple: int) -> tuple[jnp.ndarray, int]:
     nblocks = x2d.shape[0]
     padded = int(np.ceil(nblocks / row_multiple)) * row_multiple
@@ -168,16 +158,12 @@ def _pad_rows(x2d: jnp.ndarray, row_multiple: int) -> tuple[jnp.ndarray, int]:
 def quantize_blockwise8(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Any-shape float array -> ((nblocks, 4096) int8, (nblocks,) absmax).
 
-    One async jitted dispatch on the ref backend (flatten/pad/quantize
+    One async jitted dispatch on every backend (flatten/pad/quantize
     fused; shape-bucketed by jit's compilation cache)."""
     backend = get_backend()
     if backend == "ref":
         return _REF_Q8_FULL(x)
-    x2d, _ = _pad_to_blocks(jnp.asarray(x).reshape(-1).astype(jnp.float32), BLOCK8)
-    nblocks = x2d.shape[0]
-    x2d, _ = _pad_rows(x2d, ROWS)
-    q, am = quantize_blockwise8_pallas(x2d, interpret=(backend == "pallas_interpret"))
-    return q[:nblocks], am[:nblocks]
+    return _pallas_q8_full(x, interpret=(backend == "pallas_interpret"))
 
 
 def dequantize_blockwise8(
@@ -186,11 +172,31 @@ def dequantize_blockwise8(
     backend = get_backend()
     if backend == "ref":
         return _ref_d8_full(q, absmax, tuple(shape), np.dtype(dtype))
+    return _pallas_d8_full(q, absmax, tuple(shape), np.dtype(dtype),
+                           interpret=(backend == "pallas_interpret"))
+
+
+# whole-op jitted entry points (Pallas backends): flatten, block and
+# row padding, the kernel and the slice back to the wire layout are one
+# executable, so no padded intermediate is materialized between eager
+# dispatches — at full model width each one would cost a model-sized
+# device buffer
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pallas_q8_full(x, interpret):
+    x2d = _flat_blocks(x, BLOCK8)
+    nblocks = x2d.shape[0]
+    x2d, _ = _pad_rows(x2d, ROWS)
+    q, am = quantize_blockwise8_pallas(x2d, interpret=interpret)
+    return q[:nblocks], am[:nblocks]
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "interpret"))
+def _pallas_d8_full(q, absmax, shape, dtype, interpret):
     nblocks = q.shape[0]
     q, _ = _pad_rows(q, ROWS)
     absmax = jnp.pad(absmax, (0, q.shape[0] - nblocks))
-    out = dequantize_blockwise8_pallas(q, absmax, interpret=(backend == "pallas_interpret"))
-    out = out[:nblocks]
+    out = dequantize_blockwise8_pallas(q, absmax, interpret=interpret)[:nblocks]
     n = int(np.prod(shape))
     return out.reshape(-1)[:n].reshape(shape).astype(dtype)
 
@@ -202,16 +208,12 @@ def dequantize_blockwise8(
 def quantize_4bit(x: jnp.ndarray, fmt: str) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Any-shape float array -> ((nblocks, 32) packed uint8, (nblocks,) absmax).
 
-    One async jitted dispatch on the ref backend, like
+    One async jitted dispatch on every backend, like
     :func:`quantize_blockwise8`."""
     backend = get_backend()
     if backend == "ref":
         return _REF_Q4_FULL[fmt](x)
-    x2d, _ = _pad_to_blocks(jnp.asarray(x).reshape(-1).astype(jnp.float32), BLOCK4)
-    nblocks = x2d.shape[0]
-    x2d, _ = _pad_rows(x2d, ROWS4)
-    p, am = quantize_4bit_pallas(x2d, fmt=fmt, interpret=(backend == "pallas_interpret"))
-    return p[:nblocks], am[:nblocks]
+    return _pallas_q4_full(x, fmt=fmt, interpret=(backend == "pallas_interpret"))
 
 
 def dequantize_4bit(
@@ -220,13 +222,25 @@ def dequantize_4bit(
     backend = get_backend()
     if backend == "ref":
         return _ref_d4_full(packed, absmax, fmt, tuple(shape), np.dtype(dtype))
+    return _pallas_d4_full(packed, absmax, fmt, tuple(shape), np.dtype(dtype),
+                           interpret=(backend == "pallas_interpret"))
+
+
+@functools.partial(jax.jit, static_argnames=("fmt", "interpret"))
+def _pallas_q4_full(x, fmt, interpret):
+    x2d = _flat_blocks(x, BLOCK4)
+    nblocks = x2d.shape[0]
+    x2d, _ = _pad_rows(x2d, ROWS4)
+    p, am = quantize_4bit_pallas(x2d, fmt=fmt, interpret=interpret)
+    return p[:nblocks], am[:nblocks]
+
+
+@functools.partial(jax.jit, static_argnames=("fmt", "shape", "dtype", "interpret"))
+def _pallas_d4_full(packed, absmax, fmt, shape, dtype, interpret):
     nblocks = packed.shape[0]
     packed, _ = _pad_rows(packed, ROWS4)
     absmax = jnp.pad(absmax, (0, packed.shape[0] - nblocks))
-    out = dequantize_4bit_pallas(
-        packed, absmax, fmt=fmt, interpret=(backend == "pallas_interpret")
-    )
-    out = out[:nblocks]
+    out = dequantize_4bit_pallas(packed, absmax, fmt=fmt, interpret=interpret)[:nblocks]
     n = int(np.prod(shape))
     return out.reshape(-1)[:n].reshape(shape).astype(dtype)
 

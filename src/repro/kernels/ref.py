@@ -135,11 +135,14 @@ def dequant_accumulate8(
     """qs: (K, nblocks, BLOCK8) int8, absmaxes: (K, nblocks), weights: (K,)
 
     -> (nblocks, BLOCK8) fp32 = sum_k w_k * dequant(qs[k]).
+
+    Elementwise multiply-adds, not a contraction: as an einsum the TPU
+    would run it on the MXU at default precision, rounding the fp32
+    scales to bf16 — an error of up to absmax/256 per element, the size
+    of the quantization step itself.
     """
-    scale = (absmaxes / 127.0) * weights[:, None]          # (K, nblocks)
-    return jnp.einsum(
-        "kbe,kb->be", qs.astype(jnp.float32), scale.astype(jnp.float32)
-    )
+    scale = (absmaxes.astype(jnp.float32) / 127.0) * weights.astype(jnp.float32)[:, None]
+    return jnp.sum(qs.astype(jnp.float32) * scale[:, :, None], axis=0)
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils.compat import pallas_tpu_compiler_params
 
 DEFAULT_CHUNK = 256
 
@@ -112,7 +111,7 @@ def slstm_scan_pallas(
             pltpu.VMEM((H, hd), jnp.float32),  # m
         ],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
     )(gx, r)

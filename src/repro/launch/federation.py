@@ -105,6 +105,7 @@ from repro.fl.job import (
 )
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
+from repro.utils.jax_env import enable_compile_cache, jax_platform
 
 PROTO = 1
 
@@ -312,6 +313,9 @@ class FederationServer:
             "lost": {}, "handshake_timeouts": 0,
         }
         self.metrics = obs_metrics.MetricsRegistry()
+        # JAX platform of every process: the server's own, and each
+        # client's as reported in its hello
+        self.platforms: dict[str, str] = {"server": jax_platform()}
         # adaptive encode-ahead shared by every downlink sender: grows
         # from DEFAULT_ENCODE_AHEAD when the wire observes encode stalls
         # (wire bytes are bitwise-identical at any depth)
@@ -423,6 +427,7 @@ class FederationServer:
                         conn.close()
                         return
                     self._conns[name] = conn
+                    self.platforms[name] = str(hello.get("platform", "unknown"))
                     rejoined = name in self._lost
                     self._lost.discard(name)
                     self._join_cv.notify_all()
@@ -972,7 +977,8 @@ class FederationClient:
             conn.send_ctrl({"type": "hello", "client": self.name,
                             "epoch": self.epoch, "proto": PROTO,
                             "fingerprint": self.fingerprint,
-                            "reconnects": self.faults["reconnects"]})
+                            "reconnects": self.faults["reconnects"],
+                            "platform": jax_platform()})
             resp = conn.recv_ctrl()
             if resp.get("type") != "welcome":
                 code = resp.get("code")
@@ -1144,7 +1150,13 @@ def _client_cmd(spec_path: str, index: int, address: tuple[str, int]) -> list[st
 
 
 def _client_env() -> dict[str, str]:
+    """Environment of a spawned client process. The launcher's own
+    process holds the accelerator (it initialises the weights and folds
+    the uplinks); a chip belongs to one process, so every client is
+    pinned to the CPU backend rather than left to race the server for
+    it. The one-chip training path is ``run_job``, in one process."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))))
     pp = env.get("PYTHONPATH", "")
@@ -1225,6 +1237,7 @@ def run_live_federation(
             "rejects": server.rejects,
             "faults": server.faults,
             "resumed_from": server.resumed_from,
+            "platforms": dict(server.platforms),
             "telemetry": server.metrics.snapshot(),
             "wall_s": round(wall_s, 6),
             "client_exit_codes": exit_codes,
@@ -1297,6 +1310,7 @@ def main(argv: Optional[list[str]] = None) -> int:
     ap.add_argument("--epoch", type=int, default=0,
                     help="client mode: round epoch to present (rejoin)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     with open(args.spec) as fh:
         spec = json.load(fh)

@@ -22,22 +22,18 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import collectives as C
 from repro.data import dirichlet_partition
 from repro.models import create_model
 from repro.optim import adamw_init, adamw_update
-from repro.utils.compat import make_mesh, shard_map
 
 
-def make_fl_round(model, *, local_steps: int, lr: float, agg: str, mesh):
-    """One federated round as a single jitted program:
-
-    shard_map over 'pod' (each pod trains locally), then cross-pod
-    aggregation of the parameter delta with the configured wire format.
-    """
+def make_local_train(model, lr: float):
+    """A pod's local phase: AdamW over a stacked ``(local_steps, ...)``
+    batch pytree with ``lax.scan``; returns (params, opt_state, losses)."""
 
     def local_train(params, opt_state, batches):
         def one_step(carry, batch):
@@ -48,6 +44,17 @@ def make_fl_round(model, *, local_steps: int, lr: float, agg: str, mesh):
 
         (params, opt_state), losses = jax.lax.scan(one_step, (params, opt_state), batches)
         return params, opt_state, losses
+
+    return local_train
+
+
+def make_fl_round(model, *, local_steps: int, lr: float, agg: str, mesh):
+    """One federated round as a single jitted program:
+
+    shard_map over 'pod' (each pod trains locally), then cross-pod
+    aggregation of the parameter delta with the configured wire format.
+    """
+    local_train = make_local_train(model, lr)
 
     def fl_round(params, opt_state, batches):
         # shard_map keeps the (now size-1) pod dim on the batch stack
@@ -75,12 +82,12 @@ def make_fl_round(model, *, local_steps: int, lr: float, agg: str, mesh):
     pspec = P()  # params replicated within pod; pod axis handled by shard_map
     batch_spec = P("pod")  # leading dim = pod-local batches
 
-    fl_round_sm = shard_map(
+    fl_round_sm = jax.shard_map(
         fl_round,
         mesh=mesh,
         in_specs=(pspec, pspec, batch_spec),
         out_specs=(pspec, pspec, pspec),
-        check=False,
+        check_vma=False,
     )
     return jax.jit(fl_round_sm, donate_argnums=(0, 1))
 
@@ -88,7 +95,8 @@ def make_fl_round(model, *, local_steps: int, lr: float, agg: str, mesh):
 def run(args) -> dict[str, Any]:
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = create_model(cfg)
-    mesh = make_mesh((args.pods, jax.device_count() // args.pods), ("pod", "data"))
+    mesh = jax.make_mesh((args.pods, jax.device_count() // args.pods), ("pod", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
     params = model.init(jax.random.PRNGKey(args.seed))
     opt_state = adamw_init(params)
     datasets = dirichlet_partition(

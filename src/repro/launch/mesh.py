@@ -10,17 +10,22 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
-from repro.utils.compat import make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1, pod: int = 0):
     """Small mesh over however many devices exist (tests)."""
     if pod:
-        return make_mesh((pod, data, model), ("pod", "data", "model"))
-    return make_mesh((data, model), ("data", "model"))
+        return _make_mesh((pod, data, model), ("pod", "data", "model"))
+    return _make_mesh((data, model), ("data", "model"))
+
+
+def _make_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -34,15 +34,7 @@ class ParamDef:
 
 def build_params(rng: jax.Array, spec: dict[str, Any], dtype) -> dict[str, Any]:
     flat: dict[str, ParamDef] = {}
-
-    def collect(node, path):
-        if isinstance(node, ParamDef):
-            flat[path] = node
-        else:
-            for k, v in node.items():
-                collect(v, f"{path}/{k}" if path else k)
-
-    collect(spec, "")
+    _collect_defs(flat, spec, "")
     keys = jax.random.split(rng, max(len(flat), 1))
     arrays: dict[str, jnp.ndarray] = {}
     for (path, pd), key in zip(sorted(flat.items()), keys):
@@ -53,13 +45,25 @@ def build_params(rng: jax.Array, spec: dict[str, Any], dtype) -> dict[str, Any]:
         else:
             arr = (jax.random.normal(key, pd.shape, jnp.float32) * pd.scale).astype(dtype)
         arrays[path] = arr
+    return _rebuild(arrays, spec, "")
 
-    def rebuild(node, path):
-        if isinstance(node, ParamDef):
-            return arrays[path]
-        return {k: rebuild(v, f"{path}/{k}" if path else k) for k, v in node.items()}
 
-    return rebuild(spec, "")
+# module-level recursions, not closures that call themselves: such a
+# closure is a reference cycle that keeps what it captures (here, every
+# parameter array on the device) alive until the cyclic collector runs
+
+def _collect_defs(flat: dict[str, ParamDef], node: Any, path: str) -> None:
+    if isinstance(node, ParamDef):
+        flat[path] = node
+    else:
+        for k, v in node.items():
+            _collect_defs(flat, v, f"{path}/{k}" if path else k)
+
+
+def _rebuild(arrays: dict[str, jnp.ndarray], node: Any, path: str) -> Any:
+    if isinstance(node, ParamDef):
+        return arrays[path]
+    return {k: _rebuild(arrays, v, f"{path}/{k}" if path else k) for k, v in node.items()}
 
 
 def build_axes(spec: dict[str, Any]) -> dict[str, Any]:
@@ -264,20 +268,17 @@ def _sdpa(q, k, v, mask, cfg: B.ModelConfig):
 
 
 def sdpa_or_flash(q, k, v, cfg: B.ModelConfig, *, causal: bool, window: Optional[int]):
-    """Full-sequence attention; routes to the flash Pallas kernel on TPU
-
-    (O(S) HBM traffic — §Perf pair 1 iteration 2), masked jnp softmax
-    elsewhere. Shapes: q (b,s,H,hd); k,v (b,t,KV,hd)."""
+    """Full-sequence attention; routes to the flash Pallas kernel on the
+    ``pallas`` backend (O(S) HBM traffic — §Perf pair 1 iteration 2) for
+    every shape — lengths that do not tile its blocks are padded and
+    masked inside the kernel wrapper, never sent to the jnp path —
+    and to masked jnp softmax on the other backends.
+    Shapes: q (b,s,H,hd); k,v (b,t,KV,hd)."""
     from repro.kernels import ops as kops
-    from repro.kernels.flash_attention import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q
 
     bsz, s, H, hd = q.shape
     t = k.shape[1]
-    if (
-        kops.get_backend() == "pallas"
-        and s % DEFAULT_BLOCK_Q == 0
-        and t % DEFAULT_BLOCK_K == 0
-    ):
+    if kops.get_backend() == "pallas":
         from repro.kernels.flash_attention import flash_attention_pallas
 
         out = flash_attention_pallas(

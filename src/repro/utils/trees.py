@@ -44,21 +44,23 @@ def flatten_state_dict(tree: Any, prefix: str = "") -> dict[str, Any]:
     negotiation.
     """
     out: dict[str, Any] = {}
-
-    def rec(node: Any, path: str) -> None:
-        if isinstance(node, Mapping):
-            for key in sorted(node.keys()):
-                sub = f"{path}{SEP}{key}" if path else str(key)
-                rec(node[key], sub)
-        elif isinstance(node, (list, tuple)):
-            for i, item in enumerate(node):
-                sub = f"{path}{SEP}{i}" if path else str(i)
-                rec(item, sub)
-        else:
-            out[path if path else "_"] = node
-
-    rec(tree, prefix)
+    _flatten_into(out, tree, prefix)
     return out
+
+
+def _flatten_into(out: dict[str, Any], node: Any, path: str) -> None:
+    # a module-level recursion, not a closure that calls itself: such a
+    # closure is a reference cycle holding ``out`` — and with it every
+    # array of the state dict, device memory included — until Python's
+    # cyclic collector happens to run
+    if isinstance(node, Mapping):
+        for key in sorted(node.keys()):
+            _flatten_into(out, node[key], f"{path}{SEP}{key}" if path else str(key))
+    elif isinstance(node, (list, tuple)):
+        for i, item in enumerate(node):
+            _flatten_into(out, item, f"{path}{SEP}{i}" if path else str(i))
+    else:
+        out[path if path else "_"] = node
 
 
 def unflatten_state_dict(flat: Mapping[str, Any]) -> dict[str, Any]:

@@ -15,11 +15,10 @@ import pytest
 
 SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.core import collectives as C
-from repro.utils.compat import make_mesh, shard_map
 
-mesh = make_mesh((2, 2), ("pod", "data"))
+mesh = jax.make_mesh((2, 2), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 n = 10_000
 per_pod = jnp.asarray(rng.standard_normal((2, n)), jnp.float32)
@@ -34,8 +33,8 @@ def agg(x, kind):
         else:
             out = C.bucketed_quantized_pod_mean(x, bucket_bytes=4096 * 4, axis_name="pod")
         return out[None]
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=P("pod"),
-                             out_specs=P("pod"), check=False))(x)
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                                 out_specs=P("pod"), check_vma=False))(x)
 
 exact = np.asarray(agg(per_pod, "fp32"))[0]
 q = np.asarray(agg(per_pod, "int8"))[0]

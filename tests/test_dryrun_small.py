@@ -27,8 +27,8 @@ from repro.launch import roofline as RL
 from repro.models import layers as ML
 from repro.utils import hlo as H
 
-from repro.utils.compat import make_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 # shrink shapes for CI
 for name, (S, B) in {"train_4k": (128, 8), "prefill_32k": (256, 4),
                      "decode_32k": (256, 8), "long_500k": (512, 2)}.items():

@@ -2,6 +2,7 @@
 
 sweeps in interpret mode (the compiled path is TPU-only).
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -64,3 +65,35 @@ def test_flash_cross_attention_lengths():
     out = flash_attention_pallas(q, k, v, causal=False, block_q=64, block_k=64, interpret=True)
     want = ref.attention(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("sq,sk,causal", [(100, 100, True), (130, 130, True),
+                                          (40, 200, False)])
+def test_flash_pads_lengths_that_do_not_tile(sq, sk, causal):
+    """Lengths off the block grid run the kernel: queries and keys are
+    padded up to the blocks and the padded keys masked out."""
+    q, k, v = _qkv(1, 2, 2, sq, 64, seed=sq + sk, sk=sk)
+    out = flash_attention_pallas(q, k, v, causal=causal, block_q=64, block_k=64,
+                                 interpret=True)
+    assert out.shape == q.shape
+    want = ref.attention(q, k, v, causal=causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("S,window", [(128, None), (100, None), (128, 48)])
+def test_flash_gradient_matches_oracle(S, window):
+    """The custom VJP (backward through the reference attention) gives
+    the oracle's gradients for q, k and v."""
+    q, k, v = _qkv(1, 4, 2, S, 64, seed=S + 29)
+    g = jnp.asarray(np.random.default_rng(31).standard_normal(q.shape), jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * g)
+
+    flash = loss(lambda q, k, v: flash_attention_pallas(
+        q, k, v, causal=True, window=window, block_q=64, block_k=64, interpret=True))
+    oracle = loss(lambda q, k, v: ref.attention(q, k, v, causal=True, window=window))
+    got = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(oracle, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3)

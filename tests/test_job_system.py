@@ -151,3 +151,24 @@ def test_dp_sigma_changes_result():
         for k in a["final_weights"]
     ]
     assert max(diffs) > 1e-4  # noise visibly applied
+
+
+def test_compile_cache_is_placed_from_outside_or_fixed_in_checkout(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set in code;
+    unset, the cache goes to one fixed directory inside the checkout."""
+    import jax
+
+    from repro.utils import jax_env
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(jax_env.CACHE_ENV, "/placed/by/the/host")
+        assert jax_env.enable_compile_cache() == "/placed/by/the/host"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(jax_env.CACHE_ENV)
+        path = jax_env.enable_compile_cache()
+        assert path == str(jax_env.DEFAULT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert (jax_env.DEFAULT_CACHE_DIR.parent / "src" / "repro" / "fl" / "job.py").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -132,6 +132,19 @@ def test_live_ordered_rounds_bitwise_match_simulator():
     assert [r["clients"] for r in server.round_log] == [
         ["site-0", "site-1", "site-2"]] * 2
     assert server.restarts == 0 and server.bytes_up > 0 and server.bytes_down > 0
+    assert server.platforms == {"server": "cpu", "site-0": "cpu",
+                                "site-1": "cpu", "site-2": "cpu"}
+
+
+def test_spawned_clients_are_pinned_off_the_chip(monkeypatch):
+    """The launcher's process owns the accelerator; every client process
+    it spawns runs on the CPU backend, whatever the parent selected."""
+    from repro.launch.federation import _client_env
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    env = _client_env()
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert "src" in env["PYTHONPATH"]
 
 
 def test_live_concurrent_uplink_completes_and_agrees():
@@ -438,5 +451,6 @@ def test_subprocess_federation_bitwise_matches_run_job():
     }
     live = run_live_federation(spec)
     assert live["client_exit_codes"] == [0, 0]
+    assert live["platforms"] == {"server": "cpu", "site-0": "cpu", "site-1": "cpu"}
     sim = run_job(dict(spec))
     assert weights_bitwise_equal(live["final_weights"], sim["final_weights"])

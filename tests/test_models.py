@@ -226,3 +226,27 @@ def test_moe_aux_loss_and_balance():
     # aux loss O(1) for near-uniform routing at init (collapse would be ~E)
     assert 0.5 < float(metrics["aux_loss"]) < 4.0
     assert np.isfinite(float(loss))
+
+
+@pytest.mark.parametrize("builder", ["model.init", "flatten_state_dict"])
+def test_state_builders_leave_no_reference_cycle(builder):
+    """Parameters are freed as soon as the last reference goes, without
+    waiting for the cyclic collector: a self-calling closure holding the
+    arrays would keep a full model on the device after a round."""
+    import gc
+    import weakref
+
+    from repro.utils.trees import flatten_state_dict
+
+    model = create_model(get_smoke_config("qwen1.5-0.5b"))
+    gc.collect()
+    gc.disable()
+    try:
+        params = model.init(jax.random.PRNGKey(0))
+        if builder == "flatten_state_dict":
+            params = flatten_state_dict(params)
+        refs = [weakref.ref(a) for a in jax.tree_util.tree_leaves(params)]
+        del params
+        assert refs and all(r() is None for r in refs)
+    finally:
+        gc.enable()

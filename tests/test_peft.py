@@ -2,10 +2,12 @@
 ``lora`` stage, streaming low-rank aggregation, native adapters, and the
 fused collect-mode dequantize.
 
-Golden-bytes hashes pin the full container stream for the canonical
-``lora:8 -> quantize:nf4 -> crc32`` stack — the determinism contract
-(jitted SVD + sign canonicalization) the async double-encode path and
-the live federation's pipeline fingerprint both rely on.
+A golden-bytes hash pins the full container stream of the canonical
+``lora:8 -> quantize:nf4 -> crc32`` stack over fixed factors (the wire
+format below the SVD); the determinism contract (jitted SVD + sign
+canonicalization) that the async double-encode path and the live
+federation's pipeline fingerprint rely on is a same-process re-encode
+test.
 """
 import hashlib
 
@@ -167,13 +169,27 @@ def test_lora_encode_is_deterministic():
     assert h1 == h2
 
 
-def test_lora_stack_golden_bytes():
-    """Pin the full container stream of the canonical stack. If this
-    hash moves, the parameter-efficient wire format changed — bump
-    deliberately."""
+def _fixed_factors(x, rank):
+    """Seeded stand-in for the SVD: factor bits fixed by shape alone."""
+    m, n = np.shape(x)
+    rng = np.random.default_rng((m, n, rank))
+    return (rng.standard_normal((m, rank)).astype(np.float32),
+            rng.standard_normal((rank, n)).astype(np.float32))
+
+
+def test_lora_stack_golden_bytes(monkeypatch):
+    """Pin the full container stream of the canonical stack *below the
+    SVD*: the decomposition is replaced by fixed, seeded factors, so the
+    hash covers what the wire format owns — the lowrank item framing and
+    envelope, nf4 on the skipped tensors, crc32 — and not the float bits
+    a platform's SVD produces (a TPU SVD does not reproduce a CPU one).
+    If this hash moves, the parameter-efficient wire format changed —
+    bump deliberately. Same-process re-encode equality of the real SVD
+    path is ``test_lora_encode_is_deterministic``'s job."""
+    monkeypatch.setattr(ops, "low_rank_decompose", _fixed_factors)
     sd = _low_rank_sd()
     assert _stream_hash(pl.build_pipeline(LORA_STACK), sd) == \
-        "8152cc682f285cd35df0128745996080e1b69f8f1395c6e2c57471063c00d2c4"
+        "318b0208611467922691011b716a8059947b7b3d97a2aa3547581ebdcf36db03"
 
 
 def test_lora_stack_roundtrip_with_quantized_smalls():

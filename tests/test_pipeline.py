@@ -288,7 +288,10 @@ def test_unknown_stage_name_raises():
         pl.build_pipeline(["carrier-pigeon"])
 
 
-def test_third_party_stage_registers_and_runs():
+def test_third_party_stage_registers_and_runs(monkeypatch):
+    # a registration is process-global and enters every pipeline
+    # fingerprint: keep it out of the tests that share this worker
+    monkeypatch.setattr(pl, "_STAGES", dict(pl._STAGES))
     name = "test-negate"
     if name not in pl.registered_stages():
         @pl.register_stage(name)

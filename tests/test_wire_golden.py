@@ -398,10 +398,13 @@ def test_delta_stage_keeps_one_canonical_snapshot_in_process():
     assert stage._prev_dec[key] is stage._prev_enc[key]
 
 
-def test_stage_overriding_only_views_hook_runs_on_the_wire():
+def test_stage_overriding_only_views_hook_runs_on_the_wire(monkeypatch):
     """A byte stage may override only encode_item_views (the streaming
     hook); it must still be scheduled and its meta recorded in the
     envelope."""
+    # a registration is process-global and enters every pipeline
+    # fingerprint: keep it out of the tests that share this worker
+    monkeypatch.setattr(pl, "_STAGES", dict(pl._STAGES))
     name = "test-views-only-tag"
     if name not in pl.registered_stages():
         @pl.register_stage(name)
