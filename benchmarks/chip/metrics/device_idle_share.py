@@ -1,0 +1,9 @@
+"""device_idle_share (%): the share of the traced window in which no
+operation ran on the device (1 - busy / window, from the profiler)."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if not t or not t["devices"] or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
