@@ -63,6 +63,7 @@ import zlib as _zlib
 from collections.abc import Callable, Iterator, Mapping
 from typing import Any, Optional, Union
 
+import jax
 import numpy as np
 
 from repro.core import serialization as ser
@@ -76,6 +77,7 @@ from repro.core.quantization import (
     quantize_batch,
 )
 from repro.core.sparse import SparseTensor, topk_sparsify
+from repro.kernels import ops
 from repro.obs import trace as obs_trace
 from repro.peft.lowrank import LowRankDelta
 from repro.utils import mem
@@ -267,17 +269,27 @@ def _prequantize(stage: Stage, message: Message, ctx: WireContext,
     Results are bitwise-identical to the per-item path; only the
     dispatch schedule changes. Falls back silently (per-item quantize in
     the streamer loop) whenever an earlier stage could rewrite items.
+
+    A device array's first ``np.asarray`` (in :func:`_is_quantizable`)
+    is its device->host copy, which JAX keeps on the array for the later
+    ones. Traced, that copy is made up front as a ``host.d2h`` span and
+    the host arrays are what the check and :func:`quantize_batch` read.
     """
     if ctx.state.get("vstage0") is not stage:
         return
+    payload = message.payload
+    tr = obs_trace.ACTIVE
+    if tr is not None:
+        payload = {name: ops.to_host(value) if isinstance(value, jax.Array) else value
+                   for name, value in payload.items()}
     fmt_for = {
-        name: fmt for name, value in message.payload.items()
+        name: fmt for name, value in payload.items()
         if (fmt := fmt_for_name(name)) is not None
         and _is_quantizable(value, min_params)
     }
     if not fmt_for:
         return
-    pre = quantize_batch(message.payload, fmt_for)
+    pre = quantize_batch(payload, fmt_for)
     # keyed by (source value identity): a later whole-message stage may
     # swap the payload, in which case the parked results must not match
     ctx.state[("prequant", id(stage))] = {
@@ -1309,6 +1321,10 @@ class WireDecoder:
                 with mem.record_hold(_value_nbytes(value)):
                     self._sink.accept_item(name, value, self._sink_weight)
             else:
+                if isinstance(value, jax.Array):
+                    # the device->host copy _value_nbytes' np.asarray
+                    # makes (JAX keeps it on the array), timed alone
+                    value = ops.to_host(value)
                 with tr.span("agg.accept_item", "agg", item=name,
                              nbytes=_value_nbytes(value)):
                     with mem.record_hold(_value_nbytes(value)):

@@ -135,19 +135,23 @@ def _fused_quantize_group(
     The concat buffer is O(group) *compute scratch* on the sender —
     the same order as the fp32 message the sender already holds, and
     deliberately outside the MemoryMeter, which tracks transmission
-    buffers (those stay O(item) under container streaming)."""
+    buffers (those stay O(item) under container streaming).
+
+    Traced, each device->host copy is a ``host.d2h`` span and the NumPy
+    staging (the joined buffer, the joined results) ``host.pack``."""
     block = _BLOCK_OF[fmt]
     spans: list[tuple[str, Any, int, int]] = []   # name, arr, start, nblocks
     total = 0
     for name in names:
-        arr = np.asarray(items[name])
+        arr = ops.to_host(items[name])
         nb = int(np.ceil(arr.size / block))
         spans.append((name, arr, total, nb))
         total += nb
-    big = np.zeros(total * block, np.float32)
-    for _name, arr, start, _nb in spans:
-        flat = np.ascontiguousarray(arr).reshape(-1)
-        big[start * block: start * block + flat.size] = flat
+    with obs_trace.span("host.pack", "host", nbytes=4 * total * block):
+        big = np.zeros(total * block, np.float32)
+        for _name, arr, start, _nb in spans:
+            flat = np.ascontiguousarray(arr).reshape(-1)
+            big[start * block: start * block + flat.size] = flat
     qs, ams = [], []
     for lo in range(0, big.size, GROUP_SLICE_ELEMS):
         part = big[lo:lo + GROUP_SLICE_ELEMS]
@@ -155,10 +159,12 @@ def _fused_quantize_group(
             q, am = ops.quantize_blockwise8(part)
         else:
             q, am = ops.quantize_4bit(part, fmt)
-        qs.append(np.asarray(q))     # one sync per slice
-        ams.append(np.asarray(am))
-    q_np = qs[0] if len(qs) == 1 else np.concatenate(qs)
-    am_np = ams[0] if len(ams) == 1 else np.concatenate(ams)
+        qs.append(ops.to_host(q))     # one sync per slice
+        ams.append(ops.to_host(am))
+    joined = sum(p.nbytes for parts in (qs, ams) if len(parts) > 1 for p in parts)
+    with obs_trace.span("host.pack", "host", nbytes=joined):
+        q_np = qs[0] if len(qs) == 1 else np.concatenate(qs)
+        am_np = ams[0] if len(ams) == 1 else np.concatenate(ams)
     return {
         name: QuantizedTensor(q_np[start:start + nb], am_np[start:start + nb],
                               fmt, tuple(arr.shape), arr.dtype)
@@ -213,7 +219,10 @@ def _fused_dequantize_group(
     every same-format tensor are laid back to back and the blocked
     kernel runs over them one :data:`GROUP_SLICE_ELEMS` slice at a time.
     Block boundaries never span tensors, so the per-tensor slices are
-    element-wise identical to dequantizing each tensor alone."""
+    element-wise identical to dequantizing each tensor alone.
+
+    Traced, as in :func:`_fused_quantize_group`: ``host.d2h`` per
+    device->host copy, ``host.pack`` around the joins."""
     block = _BLOCK_OF[fmt]
     spans: list[tuple[str, QuantizedTensor, int, int]] = []  # name, qt, start, nblocks
     total = 0
@@ -222,8 +231,11 @@ def _fused_dequantize_group(
         nb = int(qt.absmax.shape[0])
         spans.append((name, qt, total, nb))
         total += nb
-    q_cat = np.concatenate([np.asarray(qt.payload) for _n, qt, _s, _nb in spans])
-    am_cat = np.concatenate([np.asarray(qt.absmax) for _n, qt, _s, _nb in spans])
+    qs = [ops.to_host(qt.payload) for _n, qt, _s, _nb in spans]
+    ams = [ops.to_host(qt.absmax) for _n, qt, _s, _nb in spans]
+    with obs_trace.span("host.pack", "host", nbytes=sum(p.nbytes for p in qs + ams)):
+        q_cat = np.concatenate(qs)
+        am_cat = np.concatenate(ams)
     rows = GROUP_SLICE_ELEMS // block
     parts = []
     for lo in range(0, total, rows):
@@ -234,8 +246,10 @@ def _fused_dequantize_group(
         else:
             flat = ops.dequantize_4bit(q_cat[lo:lo + n], am_cat[lo:lo + n], fmt,
                                        (n * block,), np.float32)
-        parts.append(np.asarray(flat))   # one sync per slice
-    flat_np = parts[0] if len(parts) == 1 else np.concatenate(parts)
+        parts.append(ops.to_host(flat))   # one sync per slice
+    joined = sum(p.nbytes for p in parts) if len(parts) > 1 else 0
+    with obs_trace.span("host.pack", "host", nbytes=joined):
+        flat_np = parts[0] if len(parts) == 1 else np.concatenate(parts)
     out: dict[str, np.ndarray] = {}
     for name, qt, start, _nb in spans:
         size = int(np.prod(qt.orig_shape)) if qt.orig_shape else 1

@@ -1162,6 +1162,10 @@ class ContainerStreamer:
         contiguous bytes or a scatter-gather view list
         (:data:`repro.core.serialization.Views`); views flow through to
         the driver unjoined.
+
+        Traced, one item's chunk loop is a ``stream.item`` span
+        (``nbytes``, ``chunks``); on loopback the receiver's reassembly,
+        decode and fold run inside it.
         """
         adaptive = (self.prefetch
                     if isinstance(self.prefetch, AdaptiveEncodeAhead) else None)
@@ -1178,14 +1182,19 @@ class ContainerStreamer:
         seq = 0
         for i, (_name, item) in enumerate(items):
             last_item = i == total - 1
-            for part, item_last in _chunk_iter_views(item, self.chunk_size):
-                flags = 0
-                if item_last:
-                    flags |= FLAG_ITEM_END
-                    if last_item:
-                        flags |= FLAG_EOF
-                self.driver.send(Chunk(sid, seq, part, flags))
-                seq += 1
+            first = seq
+            with obs_trace.span("stream.item", "stream",
+                                nbytes=ser.views_nbytes(item)) as sp:
+                for part, item_last in _chunk_iter_views(item, self.chunk_size):
+                    flags = 0
+                    if item_last:
+                        flags |= FLAG_ITEM_END
+                        if last_item:
+                            flags |= FLAG_EOF
+                    self.driver.send(Chunk(sid, seq, part, flags))
+                    seq += 1
+                if sp is not None:
+                    sp.args["chunks"] = seq - first
         if adaptive is not None:
             adaptive.observe(stall[0], time.perf_counter() - t0)
         return sid

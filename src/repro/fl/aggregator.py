@@ -136,7 +136,9 @@ class FedAvgAggregator(Aggregator):
         contribution (``w * x`` lands in scratch, scratch adds into the
         running sum), so folding an item allocates nothing after the
         first round — same arithmetic, same order, bitwise-equal
-        results to the naive ``sum += value * weight``.
+        results to the naive ``sum += value * weight``. Traced, a device
+        array's copy to the host is ``host.d2h`` and the arithmetic
+        ``host.fold``.
         """
         if isinstance(value, QuantizedTensor):
             raise TypeError(
@@ -144,8 +146,8 @@ class FedAvgAggregator(Aggregator):
                 "decode values on the uplink pipeline (the default) or use "
                 "QuantizedFedAvgAggregator"
             )
-        arr = np.asarray(value, dtype=np.float32)
-        with self._lock:
+        arr = np.asarray(ops.to_host(value), dtype=np.float32)
+        with self._lock, obs_trace.span("host.fold", "host", nbytes=arr.nbytes):
             acc = self._sum.get(name)
             if acc is None:
                 self._sum[name] = arr * np.float32(weight)
@@ -161,10 +163,12 @@ class FedAvgAggregator(Aggregator):
         with obs_trace.span("agg.finish", "agg"), self._lock:
             if self._weight <= 0:
                 raise RuntimeError("no results accepted")
-            out = {
-                name: (arr / self._weight).astype(np.float32)
-                for name, arr in self._sum.items()
-            }
+            with obs_trace.span("host.fold", "host",
+                                nbytes=sum(a.nbytes for a in self._sum.values())):
+                out = {
+                    name: (arr / self._weight).astype(np.float32)
+                    for name, arr in self._sum.items()
+                }
             self._sum = {}
             self._weight = 0.0
             self.accepted = 0
@@ -249,9 +253,11 @@ class QuantizedFedAvgAggregator(Aggregator):
             for name, acc in self._acc.items():
                 shape = self._shape[name]
                 n = int(np.prod(shape))
-                out[name] = (
-                    np.asarray(acc).reshape(-1)[:n].reshape(shape) * inv
-                ).astype(np.float32)
+                host = ops.to_host(acc)
+                with obs_trace.span("host.fold", "host", nbytes=4 * n):
+                    out[name] = (
+                        host.reshape(-1)[:n].reshape(shape) * inv
+                    ).astype(np.float32)
             if self._plain_names:
                 # reuse the plain aggregator's running sum (shares self._weight)
                 self._plain._weight = self._weight

@@ -76,6 +76,7 @@ from repro.kernels import ops
 from repro.fl.executor import TrainExecutor
 from repro.fl.simulator import FLSimulator, SimulationConfig
 from repro.models import create_model
+from repro.obs import trace as obs_trace
 from repro.optim import adamw_init, adamw_update
 from repro.utils.jax_env import enable_compile_cache
 from repro.utils.trees import flatten_state_dict, unflatten_state_dict
@@ -313,30 +314,32 @@ def _train_executor(
     history: Optional[list[float]] = None,
 ) -> TrainExecutor:
     def train_fn(flat_params, rnd):
-        # the task payload is consumed: device arrays the downlink decode
-        # produced become the step's parameters without a copy, and the
-        # first (donating) step frees them — a full-width client does not
-        # hold the received weights beside its training state
-        p = unflatten_state_dict(
-            {k: jnp.asarray(v) for k, v in flat_params.items()}
-        )
-        opt = adamw_init(p)
-        loss = None
-        # round-keyed sampling makes the update a pure function of
-        # (params, rnd): a client that reconnects or re-executes a round
-        # after a fault regenerates the identical batches, so chaos and
-        # resume runs stay bitwise-equal to clean ones
-        for step in range(spec["local_steps"]):
-            batch = {
-                k: jnp.asarray(v)
-                for k, v in data.sample_at(
-                    spec["batch"], rnd * spec["local_steps"] + step
-                ).items()
-            }
-            p, opt, loss = local_step(p, opt, batch)
-        if history is not None:
-            history.append(float(loss))
-        return flatten_state_dict(p), spec["batch"] * spec["local_steps"], {"loss": float(loss)}
+        with obs_trace.span("client.train", "client", client=name, round=rnd,
+                            steps=spec["local_steps"]):
+            # the task payload is consumed: device arrays the downlink decode
+            # produced become the step's parameters without a copy, and the
+            # first (donating) step frees them — a full-width client does not
+            # hold the received weights beside its training state
+            p = unflatten_state_dict(
+                {k: jnp.asarray(v) for k, v in flat_params.items()}
+            )
+            opt = adamw_init(p)
+            loss = None
+            # round-keyed sampling makes the update a pure function of
+            # (params, rnd): a client that reconnects or re-executes a round
+            # after a fault regenerates the identical batches, so chaos and
+            # resume runs stay bitwise-equal to clean ones
+            for step in range(spec["local_steps"]):
+                batch = {
+                    k: jnp.asarray(v)
+                    for k, v in data.sample_at(
+                        spec["batch"], rnd * spec["local_steps"] + step
+                    ).items()
+                }
+                p, opt, loss = local_step(p, opt, batch)
+            if history is not None:
+                history.append(float(loss))
+            return flatten_state_dict(p), spec["batch"] * spec["local_steps"], {"loss": float(loss)}
 
     return TrainExecutor(name, train_fn)
 

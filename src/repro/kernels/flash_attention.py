@@ -113,6 +113,7 @@ def _flash_forward(q, k, v, causal, window, block_q, block_k, interpret):
         out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bsz, H, sq_p, hd), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(qp, kp, vp)
     return out[:, :, :sq] if sq_p != sq else out
 

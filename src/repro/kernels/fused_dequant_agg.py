@@ -57,6 +57,7 @@ def dequant_accumulate8_pallas(
         out_specs=pl.BlockSpec((ROWS, BLOCK8), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nblocks, BLOCK8), jnp.float32),
         interpret=interpret,
+        name="dequant_accumulate8",
     )(
         qs,
         absmaxes.astype(jnp.float32).reshape(K, nblocks, 1),
@@ -104,4 +105,5 @@ def dequant_accumulate8_into_pallas(
         out_shape=jax.ShapeDtypeStruct((nblocks, BLOCK8), jnp.float32),
         input_output_aliases={0: 0},
         interpret=interpret,
+        name="dequant_accumulate8_into",
     )(acc, q, absmax.astype(jnp.float32).reshape(nblocks, 1), w)

@@ -15,6 +15,15 @@ whole message batch their dispatches and block once via
 :func:`block_until_ready` (see ``repro.core.quantization.
 quantize_batch``) instead of syncing per tensor inside the streamer
 loop.
+
+Traced runs (``repro.obs.trace.ACTIVE`` set) see every host<->device
+boundary here, around the same calls an untraced run makes: the codec
+and fold entry points open ``dev.dispatch`` (``kind``, ``elems``) and,
+when NumPy arguments ride up with the dispatch, ``host.h2d`` inside it
+(``nbytes``: the host's hold of that dispatch, the copy's synchronous
+part); :func:`block_until_ready` opens ``dev.sync``; :func:`to_host` is
+``np.asarray`` with a device array's wait (``dev.sync``) and copy
+(``host.d2h``, ``nbytes``) timed apart.
 """
 from __future__ import annotations
 
@@ -27,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
+from repro.obs import trace as obs_trace
 
 # jitted ref-backend entry points (the ref functions build 15-compare /
 # 16-select networks — uncompiled tracing per call would dominate on CPU)
@@ -45,7 +55,46 @@ _REF_D4 = {
 def block_until_ready(values) -> None:
     """Barrier for a batch of async-dispatched op results (pytree of
     arrays; non-JAX leaves pass through untouched)."""
-    jax.block_until_ready(values)
+    with obs_trace.span("dev.sync", "dev"):
+        jax.block_until_ready(values)
+
+
+def to_host(x) -> np.ndarray:
+    """``np.asarray(x)``. Traced, a device array is first waited for
+    under ``dev.sync`` (``np.asarray`` waits at the same point), so that
+    ``host.d2h`` times the copy alone; anything else is on the host
+    already and converts untimed."""
+    tr = obs_trace.ACTIVE
+    if tr is None or not isinstance(x, jax.Array):
+        return np.asarray(x)
+    with tr.span("dev.sync", "dev"):
+        x.block_until_ready()
+    with tr.span("host.d2h", "host", nbytes=int(x.nbytes)):
+        return np.asarray(x)
+
+
+_NOOP = contextlib.nullcontext()
+
+
+@contextlib.contextmanager
+def _traced_dispatch(tr, kind: str, elems: int, args: tuple):
+    up = sum(a.nbytes for a in args if isinstance(a, np.ndarray))
+    with tr.span("dev.dispatch", "dev", kind=kind, elems=elems):
+        if not up:
+            yield
+            return
+        with tr.span("host.h2d", "host", nbytes=int(up)):
+            yield
+
+
+def _dispatch_span(kind: str, elems, *args):
+    """``dev.dispatch`` around one entry point's dispatch (and
+    ``host.h2d`` for the NumPy ``args`` it carries up); a shared no-op
+    when tracing is off."""
+    tr = obs_trace.ACTIVE
+    if tr is None:
+        return _NOOP
+    return _traced_dispatch(tr, kind, int(elems), args)
 
 
 def _flat_blocks(x: jnp.ndarray, block: int) -> jnp.ndarray:
@@ -160,20 +209,22 @@ def quantize_blockwise8(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 
     One async jitted dispatch on every backend (flatten/pad/quantize
     fused; shape-bucketed by jit's compilation cache)."""
-    backend = get_backend()
-    if backend == "ref":
-        return _REF_Q8_FULL(x)
-    return _pallas_q8_full(x, interpret=(backend == "pallas_interpret"))
+    with _dispatch_span("q8", x.size, x):
+        backend = get_backend()
+        if backend == "ref":
+            return _REF_Q8_FULL(x)
+        return _pallas_q8_full(x, interpret=(backend == "pallas_interpret"))
 
 
 def dequantize_blockwise8(
     q: jnp.ndarray, absmax: jnp.ndarray, shape, dtype=jnp.float32
 ) -> jnp.ndarray:
-    backend = get_backend()
-    if backend == "ref":
-        return _ref_d8_full(q, absmax, tuple(shape), np.dtype(dtype))
-    return _pallas_d8_full(q, absmax, tuple(shape), np.dtype(dtype),
-                           interpret=(backend == "pallas_interpret"))
+    with _dispatch_span("d8", np.prod(shape), q, absmax):
+        backend = get_backend()
+        if backend == "ref":
+            return _ref_d8_full(q, absmax, tuple(shape), np.dtype(dtype))
+        return _pallas_d8_full(q, absmax, tuple(shape), np.dtype(dtype),
+                               interpret=(backend == "pallas_interpret"))
 
 
 # whole-op jitted entry points (Pallas backends): flatten, block and
@@ -210,20 +261,22 @@ def quantize_4bit(x: jnp.ndarray, fmt: str) -> tuple[jnp.ndarray, jnp.ndarray]:
 
     One async jitted dispatch on every backend, like
     :func:`quantize_blockwise8`."""
-    backend = get_backend()
-    if backend == "ref":
-        return _REF_Q4_FULL[fmt](x)
-    return _pallas_q4_full(x, fmt=fmt, interpret=(backend == "pallas_interpret"))
+    with _dispatch_span("q4", x.size, x):
+        backend = get_backend()
+        if backend == "ref":
+            return _REF_Q4_FULL[fmt](x)
+        return _pallas_q4_full(x, fmt=fmt, interpret=(backend == "pallas_interpret"))
 
 
 def dequantize_4bit(
     packed: jnp.ndarray, absmax: jnp.ndarray, fmt: str, shape, dtype=jnp.float32
 ) -> jnp.ndarray:
-    backend = get_backend()
-    if backend == "ref":
-        return _ref_d4_full(packed, absmax, fmt, tuple(shape), np.dtype(dtype))
-    return _pallas_d4_full(packed, absmax, fmt, tuple(shape), np.dtype(dtype),
-                           interpret=(backend == "pallas_interpret"))
+    with _dispatch_span("d4", np.prod(shape), packed, absmax):
+        backend = get_backend()
+        if backend == "ref":
+            return _ref_d4_full(packed, absmax, fmt, tuple(shape), np.dtype(dtype))
+        return _pallas_d4_full(packed, absmax, fmt, tuple(shape), np.dtype(dtype),
+                               interpret=(backend == "pallas_interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "interpret"))
@@ -298,22 +351,23 @@ def dequant_accumulate8_into(
     accumulator; callers slice their flat view to the original element
     count (exactly like the other blocked ops).
     """
-    backend = get_backend()
-    if backend == "ref":
+    with _dispatch_span("fold8", q.shape[0] * q.shape[1], q, absmax):
+        backend = get_backend()
+        if backend == "ref":
+            if acc is None:
+                acc = jnp.zeros(q.shape, jnp.float32)
+            return _REF_FOLD8(acc, jnp.asarray(q), jnp.asarray(absmax),
+                              jnp.float32(weight))
+        nblocks = q.shape[0]
+        q, _ = _pad_rows(q, ROWS)
+        absmax = jnp.pad(absmax, (0, q.shape[0] - nblocks))
         if acc is None:
             acc = jnp.zeros(q.shape, jnp.float32)
-        return _REF_FOLD8(acc, jnp.asarray(q), jnp.asarray(absmax),
-                          jnp.float32(weight))
-    nblocks = q.shape[0]
-    q, _ = _pad_rows(q, ROWS)
-    absmax = jnp.pad(absmax, (0, q.shape[0] - nblocks))
-    if acc is None:
-        acc = jnp.zeros(q.shape, jnp.float32)
-    assert acc.shape == q.shape, (acc.shape, q.shape)
-    return dequant_accumulate8_into_pallas(
-        acc, q, absmax, jnp.float32(weight),
-        interpret=(backend == "pallas_interpret"),
-    )
+        assert acc.shape == q.shape, (acc.shape, q.shape)
+        return dequant_accumulate8_into_pallas(
+            acc, q, absmax, jnp.float32(weight),
+            interpret=(backend == "pallas_interpret"),
+        )
 
 
 # ---------------------------------------------------------------------------

@@ -61,6 +61,7 @@ def quantize_blockwise8_pallas(x2d: jnp.ndarray, *, interpret: bool = False):
             jax.ShapeDtypeStruct((nblocks, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="quantize_blockwise8",
     )(x2d)
     return q, absmax.reshape(nblocks)
 
@@ -80,4 +81,5 @@ def dequantize_blockwise8_pallas(q: jnp.ndarray, absmax: jnp.ndarray, *, interpr
         out_specs=pl.BlockSpec((ROWS, BLOCK8), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nblocks, BLOCK8), jnp.float32),
         interpret=interpret,
+        name="dequantize_blockwise8",
     )(q, absmax.astype(jnp.float32).reshape(nblocks, 1))

@@ -133,6 +133,7 @@ def quantize_4bit_pallas(x2d: jnp.ndarray, *, fmt: str, interpret: bool = False)
             jax.ShapeDtypeStruct((1, nblocks), jnp.float32),
         ],
         interpret=interpret,
+        name="quantize_4bit",
     )(x2d.T)
     return packed_t.T, absmax.reshape(nblocks)
 
@@ -154,5 +155,6 @@ def dequantize_4bit_pallas(
         out_specs=pl.BlockSpec((BLOCK4, ROWS4), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((BLOCK4, nblocks), jnp.float32),
         interpret=interpret,
+        name="dequantize_4bit",
     )(packed.T, absmax.astype(jnp.float32).reshape(1, nblocks))
     return out_t.T

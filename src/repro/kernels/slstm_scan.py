@@ -111,6 +111,7 @@ def slstm_scan_pallas(
             pltpu.VMEM((H, hd), jnp.float32),  # m
         ],
         interpret=interpret,
+        name="slstm_scan",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),

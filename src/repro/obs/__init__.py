@@ -1,11 +1,14 @@
 """Unified observability plane: span tracing + metrics registry.
 
-Two small, dependency-free modules:
+Two small modules, light to import (``trace`` loads ``jax.profiler``
+only when a tracer is built):
 
 * :mod:`repro.obs.trace` — a thread-safe span tracer with **dual
   clocks** (wall clock and the scheduler's simulated clock), nested
-  spans, a bounded ring-buffer flight recorder, and Chrome
-  trace-event JSON export viewable in Perfetto / ``chrome://tracing``.
+  spans mirrored as ``jax.profiler.TraceAnnotation`` (so they land in a
+  profiler trace beside the device's operations), a bounded ring-buffer
+  flight recorder, and Chrome trace-event JSON export viewable in
+  Perfetto / ``chrome://tracing``.
 * :mod:`repro.obs.metrics` — a registry of labeled counters / gauges /
   histograms with JSON-safe snapshots; ``TrafficStats``,
   ``RuntimeStats`` and ``MemoryMeter`` publish into it instead of

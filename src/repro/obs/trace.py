@@ -3,10 +3,10 @@
 One :class:`Tracer` records two kinds of timestamps into a single
 bounded ring buffer (the flight recorder):
 
-* **wall-clock spans/instants/counters** — ``perf_counter``-based, one
-  Perfetto track per real thread (``pid`` :data:`PID_WALL`). These show
-  where host time goes: pipeline stage encode/decode, kernel dispatch,
-  socket writes.
+* **wall-clock spans** — ``perf_counter``-based, one Perfetto track per
+  real thread (``pid`` :data:`PID_WALL`). These show where host time
+  goes: pipeline stage encode/decode, kernel dispatch, host<->device
+  copies, socket writes. Counts (bytes, elements) ride on span args.
 * **simulated-clock spans/instants/counters** — explicit timestamps in
   simulated seconds from the event scheduler (``pid`` :data:`PID_SIM`),
   one track per client. These show the federation's *timeline*:
@@ -18,6 +18,12 @@ format): load the file in https://ui.perfetto.dev or ``chrome://tracing``
 and the two clocks appear as two processes, "wall clock" and
 "simulated time". :func:`validate_chrome_trace` is the schema check the
 test suite and CI run over every exported trace.
+
+Every wall-clock span also enters ``jax.profiler.TraceAnnotation`` under
+its own name, so a ``jax.profiler`` trace taken around a traced run
+holds the program's spans as host events on the profiler's clock, next
+to the device's operations. ``jax.profiler`` is imported when a
+:class:`Tracer` is built: an untraced process imports nothing for it.
 
 Activation mirrors :class:`repro.utils.mem.MemoryMeter`: a module-level
 :data:`ACTIVE` slot, set by the :func:`activate` context manager. Hot
@@ -79,14 +85,15 @@ def span(name: str, cat: str = "", **args: Any) -> Any:
 
 
 class _Span:
-    """One in-flight wall-clock span (context manager).
+    """One in-flight wall-clock span (context manager), mirrored by a
+    profiler annotation of the same name.
 
     ``args`` stays attached to the emitted event by reference, so a
     caller may still fill in late-known fields (byte counts) inside the
     ``with`` block after the traced call returned.
     """
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_sim_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_sim_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: dict[str, Any]) -> None:
@@ -96,13 +103,17 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
-        sim = self._tracer.sim_clock
+        tr = self._tracer
+        self._ann = tr._annotation(self.name)
+        self._ann.__enter__()
+        sim = tr.sim_clock
         self._sim_t0 = sim() if sim is not None else None
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
         tr = self._tracer
         if self._sim_t0 is not None:
             self.args["sim_t"] = round(self._sim_t0, 9)
@@ -131,8 +142,11 @@ class Tracer:
                  sim_clock: Optional[Callable[[], float]] = None) -> None:
         if capacity <= 0:
             raise ValueError(f"capacity must be positive, got {capacity}")
+        from jax import profiler
+
         self.capacity = capacity
         self.sim_clock = sim_clock
+        self._annotation = profiler.TraceAnnotation
         self._events: deque = deque(maxlen=capacity)
         self._epoch_ns = time.perf_counter_ns()
         self._lock = threading.Lock()
@@ -163,28 +177,11 @@ class Tracer:
     def dropped(self) -> int:
         return self._total - len(self._events)
 
-    def _wall_ts(self) -> float:
-        return (time.perf_counter_ns() - self._epoch_ns) / 1000.0
-
     # -- wall-clock events --------------------------------------------------
     def span(self, name: str, cat: str = "", **args: Any) -> _Span:
         """A nested wall-clock span (context manager). Spans opened on
         one thread nest by containment on that thread's track."""
         return _Span(self, name, cat, args)
-
-    def instant(self, name: str, cat: str = "", **args: Any) -> None:
-        self._emit({
-            "ph": "i", "name": name, "cat": cat or "instant",
-            "pid": PID_WALL, "tid": self._wall_tid(),
-            "ts": self._wall_ts(), "s": "t", "args": args,
-        })
-
-    def counter(self, name: str, value: float, cat: str = "") -> None:
-        self._emit({
-            "ph": "C", "name": name, "cat": cat or "counter",
-            "pid": PID_WALL, "tid": 0,
-            "ts": self._wall_ts(), "args": {"value": float(value)},
-        })
 
     # -- simulated-clock events ---------------------------------------------
     def sim_span(self, name: str, t0_s: float, t1_s: float, track: str,

@@ -33,8 +33,10 @@ def test_tracer_exports_valid_dual_clock_trace():
     with tr.span("outer", "test", round=0):
         with tr.span("inner", "test", item="w"):
             pass
-    tr.instant("mark", "test", seq=1)
-    tr.counter("depth", 3)
+    with tr.span("mark", "test", seq=1):
+        pass
+    with tr.span("depth", "test", nbytes=3):
+        pass
     tr.sim_span("uplink", 1.0, 2.5, track="site-0", wire_bytes=64)
     tr.sim_instant("arrival", 2.5, track="site-0")
     tr.sim_counter("queue_depth", 2.5, 4)
@@ -71,12 +73,14 @@ def test_span_args_attach_by_reference_for_late_byte_counts():
 
 def test_ring_buffer_bounds_memory_and_reports_drops():
     tr = Tracer(capacity=8)
-    for i in range(100):
-        tr.instant(f"e{i}")
+    for i in range(50):
+        with tr.span(f"e{2 * i}"):
+            pass
+        tr.sim_instant(f"e{2 * i + 1}", float(i), track="site-0")
     assert tr.total_events == 100 and tr.dropped == 92
     obj = tr.chrome_trace()
     assert validate_chrome_trace(obj)
-    names = [ev["name"] for ev in obj["traceEvents"] if ev["ph"] == "i"]
+    names = [ev["name"] for ev in obj["traceEvents"] if ev["ph"] in ("X", "i")]
     assert names == [f"e{i}" for i in range(92, 100)]  # newest win
     assert obj["otherData"]["dropped_events"] == 92
 
