@@ -252,10 +252,10 @@ def _is_quantizable(value: Any, min_params: int) -> bool:
     # factor/index payloads still compress under the byte stages)
     if isinstance(value, (QuantizedTensor, SparseTensor, LowRankDelta)):
         return False
-    arr = np.asarray(value)
-    return bool(
-        np.issubdtype(arr.dtype, np.floating) and int(np.prod(arr.shape)) >= min_params
-    )
+    # a device array's dtype and size are read where it is: converting it
+    # would copy it to the host
+    arr = value if isinstance(value, (jax.Array, np.ndarray)) else np.asarray(value)
+    return bool(np.issubdtype(arr.dtype, np.floating) and int(arr.size) >= min_params)
 
 
 def _prequantize(stage: Stage, message: Message, ctx: WireContext,
@@ -270,26 +270,20 @@ def _prequantize(stage: Stage, message: Message, ctx: WireContext,
     dispatch schedule changes. Falls back silently (per-item quantize in
     the streamer loop) whenever an earlier stage could rewrite items.
 
-    A device array's first ``np.asarray`` (in :func:`_is_quantizable`)
-    is its device->host copy, which JAX keeps on the array for the later
-    ones. Traced, that copy is made up front as a ``host.d2h`` span and
-    the host arrays are what the check and :func:`quantize_batch` read.
+    Device arrays (a client's trained weights) stay where they are:
+    :func:`quantize_batch` reads them on the device and only their codes
+    and absmaxes come to the host, traced or not.
     """
     if ctx.state.get("vstage0") is not stage:
         return
-    payload = message.payload
-    tr = obs_trace.ACTIVE
-    if tr is not None:
-        payload = {name: ops.to_host(value) if isinstance(value, jax.Array) else value
-                   for name, value in payload.items()}
     fmt_for = {
-        name: fmt for name, value in payload.items()
+        name: fmt for name, value in message.payload.items()
         if (fmt := fmt_for_name(name)) is not None
         and _is_quantizable(value, min_params)
     }
     if not fmt_for:
         return
-    pre = quantize_batch(payload, fmt_for)
+    pre = quantize_batch(message.payload, fmt_for)
     # keyed by (source value identity): a later whole-message stage may
     # swap the payload, in which case the parked results must not match
     ctx.state[("prequant", id(stage))] = {

@@ -21,6 +21,7 @@ applied only at the four filter points (see ``repro.core.filters``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections.abc import Mapping
 from typing import Any, Optional
 
@@ -111,64 +112,108 @@ def quantize_state_dict(sd: Mapping[str, jnp.ndarray], fmt: str) -> dict[str, Qu
 _BLOCK_OF = {"blockwise8": 4096, "fp4": 64, "nf4": 64}
 
 
-#: elements per kernel dispatch when a fused group is larger: a whole
-#: model in one dispatch needs its fp32 input plus the kernel's padded
-#: working copies on the device at once (~10 GB for a 0.6B-parameter
-#: model, most of a 16 GB TPU), while a bounded slice keeps the device
-#: footprint O(slice). A multiple of every format's block size times its
-#: kernel's grid rows, so slice boundaries never split a block and each
-#: slice is padded the same way the whole group would be.
+#: elements per kernel dispatch of a fused group. Each slice of the
+#: group's joined, block-padded layout is built on the device just
+#: before its dispatch, so the encoder's device scratch is one slice of
+#: float32 input, not the whole group: a whole model joined at once
+#: needs its fp32 input plus the kernel's padded working copies (~10 GB
+#: for a 0.6B-parameter model, most of a 16 GB TPU). A multiple of
+#: every format's block size times its kernel's grid rows, so slice
+#: boundaries never split a block and each slice is padded the same way
+#: the whole group would be.
 GROUP_SLICE_ELEMS = 1 << 26
 
 
+@functools.partial(jax.jit, static_argnames=("cuts", "size"))
+def _join_slice(pieces, cuts, size):
+    """One ``size``-element float32 slice of a group's joined layout:
+    for each ``(at, a, b)`` of ``cuts``, elements ``[a, b)`` of the
+    matching flattened piece land at ``at``; the rest is zero padding."""
+    parts, pos = [], 0
+    for x, (at, a, b) in zip(pieces, cuts):
+        if at > pos:
+            parts.append(jnp.zeros(at - pos, jnp.float32))
+        if x.ndim > 1:
+            # cut the leading rows the slice takes first: flattening the
+            # whole array would relayout all of it on the device
+            row = int(np.prod(x.shape[1:]))
+            r0 = a // row
+            x, a, b = x[r0:-(-b // row)], a - r0 * row, b - r0 * row
+        parts.append(x.reshape(-1)[a:b].astype(jnp.float32))
+        pos = at + b - a
+    if pos < size:
+        parts.append(jnp.zeros(size - pos, jnp.float32))
+    return jnp.concatenate(parts)
+
+
 def _fused_quantize_group(
-    items: Mapping[str, Any], names: list[str], fmt: str
+    values: Mapping[str, Any], fmt: str
 ) -> dict[str, QuantizedTensor]:
     """One kernel dispatch per :data:`GROUP_SLICE_ELEMS` of a whole
-    format group: every tensor is padded to whole quant blocks (exactly
-    the per-tensor wire layout) and laid back to back in one fp32
-    buffer, the blocked kernel runs over it slice by slice, and each
+    format group. The group's layout (every tensor padded to whole quant
+    blocks, exactly the per-tensor wire layout, laid back to back) is
+    worked out from shapes alone; each slice of it is built on the
+    device from the pieces of the tensors that fall in it: a device
+    array is sliced where it is, a host array sends up only the
+    contiguous views the slice needs, and the padding and the float32
+    cast are device work. Each slice's codes and absmaxes stay on the
+    device, are joined there, and come to the host once per group; each
     tensor's payload/absmax are row slices of the joined result. Block
     boundaries never span tensors or slices, so the sliced payloads are
     bitwise-identical to quantizing each tensor alone.
 
-    The concat buffer is O(group) *compute scratch* on the sender —
-    the same order as the fp32 message the sender already holds, and
-    deliberately outside the MemoryMeter, which tracks transmission
-    buffers (those stay O(item) under container streaming).
-
-    Traced, each device->host copy is a ``host.d2h`` span and the NumPy
-    staging (the joined buffer, the joined results) ``host.pack``."""
+    Traced, ``dev.join`` times each slice's assembly (with ``host.h2d``
+    around the upload of its host pieces, ``nbytes``) and the join of
+    the results; ``host.d2h`` times the group's two copies down."""
     block = _BLOCK_OF[fmt]
-    spans: list[tuple[str, Any, int, int]] = []   # name, arr, start, nblocks
+    spans: list[tuple[str, Any, int, int]] = []   # name, value, first elem, nblocks
     total = 0
-    for name in names:
-        arr = ops.to_host(items[name])
-        nb = int(np.ceil(arr.size / block))
-        spans.append((name, arr, total, nb))
+    for name, value in values.items():
+        nb = -(-int(value.size) // block)
+        spans.append((name, value, total * block, nb))
         total += nb
-    with obs_trace.span("host.pack", "host", nbytes=4 * total * block):
-        big = np.zeros(total * block, np.float32)
-        for _name, arr, start, _nb in spans:
-            flat = np.ascontiguousarray(arr).reshape(-1)
-            big[start * block: start * block + flat.size] = flat
+    flats: dict[str, np.ndarray] = {}   # host tensors, flattened once
     qs, ams = [], []
-    for lo in range(0, big.size, GROUP_SLICE_ELEMS):
-        part = big[lo:lo + GROUP_SLICE_ELEMS]
+    for lo in range(0, total * block, GROUP_SLICE_ELEMS):
+        hi = min(lo + GROUP_SLICE_ELEMS, total * block)
+        pieces, cuts, host = [], [], []
+        for name, value, first, _nb in spans:
+            a, b = max(lo, first), min(hi, first + int(value.size))
+            if a >= b:
+                continue
+            if isinstance(value, jax.Array):
+                pieces.append(value)
+                cuts.append((a - lo, a - first, b - first))
+            else:
+                if name not in flats:
+                    flats[name] = value.reshape(-1)
+                host.append(len(pieces))
+                pieces.append(flats[name][a - first:b - first])
+                cuts.append((a - lo, 0, b - a))
+        with obs_trace.span("dev.join", "dev"):
+            if host:
+                up = [pieces[i] for i in host]
+                with obs_trace.span("host.h2d", "host",
+                                    nbytes=sum(p.nbytes for p in up)):
+                    up = jax.device_put(up)
+                for i, x in zip(host, up):
+                    pieces[i] = x
+            part = _join_slice(tuple(pieces), tuple(cuts), hi - lo)
         if fmt == "blockwise8":
             q, am = ops.quantize_blockwise8(part)
         else:
             q, am = ops.quantize_4bit(part, fmt)
-        qs.append(ops.to_host(q))     # one sync per slice
-        ams.append(ops.to_host(am))
-    joined = sum(p.nbytes for parts in (qs, ams) if len(parts) > 1 for p in parts)
-    with obs_trace.span("host.pack", "host", nbytes=joined):
-        q_np = qs[0] if len(qs) == 1 else np.concatenate(qs)
-        am_np = ams[0] if len(ams) == 1 else np.concatenate(ams)
+        qs.append(q)
+        ams.append(am)
+    if len(qs) > 1:
+        with obs_trace.span("dev.join", "dev"):
+            qs, ams = [jnp.concatenate(qs)], [jnp.concatenate(ams)]
+    q_np, am_np = ops.to_host(qs[0]), ops.to_host(ams[0])
     return {
-        name: QuantizedTensor(q_np[start:start + nb], am_np[start:start + nb],
-                              fmt, tuple(arr.shape), arr.dtype)
-        for name, arr, start, nb in spans
+        name: QuantizedTensor(q_np[first // block:first // block + nb],
+                              am_np[first // block:first // block + nb],
+                              fmt, tuple(value.shape), np.dtype(value.dtype))
+        for name, value, first, nb in spans
     }
 
 
@@ -176,9 +221,9 @@ def quantize_batch(
     items: Mapping[str, Any], fmt_for: Mapping[str, str]
 ) -> dict[str, QuantizedTensor]:
     """Whole-message quantization: one kernel dispatch **per format
-    group** (all same-format tensors concatenated block-aligned; groups
-    past :data:`GROUP_SLICE_ELEMS` dispatch once per slice), one device
-    sync per dispatch.
+    group** (all same-format tensors joined block-aligned on the device;
+    groups past :data:`GROUP_SLICE_ELEMS` dispatch once per slice), one
+    copy of the group's codes and absmaxes to the host.
 
     This is the wire hot path's replacement for per-tensor
     dispatch-then-sync inside the streamer loop: serializing item k
@@ -191,23 +236,29 @@ def quantize_batch(
     dispatch schedule changes (asserted by the golden-bytes suite).
     """
     out: dict[str, QuantizedTensor] = {}
-    groups: dict[str, list[str]] = {}
+    groups: dict[str, dict[str, Any]] = {}
     for name, value in items.items():
         fmt = fmt_for.get(name)
         if fmt is None:
             continue
         if fmt in _BLOCK_OF:
-            groups.setdefault(fmt, []).append(name)
+            if not isinstance(value, (jax.Array, np.ndarray)):
+                value = np.asarray(value)
+            groups.setdefault(fmt, {})[name] = value
         else:  # fp32/fp16/bf16 casts: cheap host-side per-tensor work
             out[name] = quantize(np.asarray(value), fmt)
     tr = obs_trace.ACTIVE
-    for fmt, names in groups.items():
+    for fmt, values in groups.items():
         if tr is None:
-            out.update(_fused_quantize_group(items, names, fmt))
+            out.update(_fused_quantize_group(values, fmt))
         else:
+            resident = sum(int(v.nbytes) for v in values.values()
+                           if isinstance(v, jax.Array))
+            uploaded = sum(int(v.nbytes) for v in values.values()) - resident
             with tr.span("kernel.quantize_batch", "kernel", fmt=fmt,
-                         items=len(names)):
-                out.update(_fused_quantize_group(items, names, fmt))
+                         items=len(values), resident_bytes=resident,
+                         uploaded_bytes=uploaded):
+                out.update(_fused_quantize_group(values, fmt))
     ops.block_until_ready([(qt.payload, qt.absmax) for qt in out.values()])
     return out
 

@@ -23,7 +23,7 @@ import os
 import numpy as np
 import pytest
 
-SPANS = ("dev.dispatch", "host.h2d", "dev.sync", "host.d2h", "host.pack",
+SPANS = ("dev.dispatch", "dev.join", "host.h2d", "dev.sync", "host.d2h",
          "host.fold", "stream.item", "client.train")
 BLOCK8, BLOCK4 = 4096, 64
 
@@ -84,20 +84,23 @@ def _layout(path: str, weights: dict) -> tuple[int, int, int, int]:
 def _expected_bytes(path: str, weights: dict) -> tuple[int, int]:
     """(host.h2d, host.d2h) bytes of one round of two clients, from the
     code path. Every float leaf is quantized; a format group is joined
-    at whole blocks and quantized, from a NumPy buffer, in slices; each
-    message's codes and absmaxes (4 bytes a block) come back to the
-    host. blockwise8: the downlink decodes from NumPy payloads, the
-    clients train on the decoded device arrays, the device folds each
-    uplink item from its NumPy payload, and ``finish`` copies the
-    accumulators back. nf4: each uplink item is decoded on the device
-    and copied back for the host fold."""
+    at whole blocks on the device, slice by slice, from the tensors
+    where they are: a downlink's NumPy leaves go up as they are, an
+    uplink's trained weights are already on the device. Each message's
+    codes and absmaxes (4 bytes a block) come to the host once.
+    blockwise8: the downlink decodes from NumPy payloads, the clients
+    train on the decoded device arrays, the device folds each uplink
+    item from its NumPy payload, and ``finish`` copies the accumulators
+    back. nf4: each uplink item is decoded on the device and copied
+    back for the host fold."""
     params, block, blocks, code = _layout(path, weights)
     joined, codes = 4 * block * blocks, (code + 4) * blocks
+    # up: 2 downlinks' leaves; 2 downlink decodes' and 2 folds' (b8) or
+    # 4 decodes' (nf4) codes. Down: 4 encodes' codes, then b8's
+    # accumulators or nf4's 2 decoded uplinks
     if path == "qwen05b-b8-stream":
-        # 4 encodes up; 2 downlink decodes and 2 folds up. Down: the 2
-        # uplink inputs, 4 encodes' codes, the accumulators
-        return 4 * joined + 4 * codes, 2 * 4 * params + 4 * codes + joined
-    return 4 * joined + 4 * codes, 2 * 4 * params + 4 * codes + 2 * 4 * params
+        return 2 * 4 * params + 4 * codes, 4 * codes + joined
+    return 2 * 4 * params + 4 * codes, 4 * codes + 2 * 4 * params
 
 
 def _expected_elems(path: str, weights: dict) -> dict[str, int]:
@@ -138,6 +141,8 @@ def test_copy_bytes_follow_the_parameters(runs):
 
 
 def test_h2d_rides_inside_its_dispatch(runs):
+    """Every upload is inside the dispatch that carries it, or inside
+    the on-device join of the slice it feeds."""
     _path, traced, _ = runs
     by_tid: dict = {}
     for e in traced["spans"]:
@@ -145,7 +150,7 @@ def test_h2d_rides_inside_its_dispatch(runs):
     for e in traced["spans"]:
         if e["name"] != "host.h2d":
             continue
-        assert any(d["name"] == "dev.dispatch" and d["ts"] <= e["ts"]
+        assert any(d["name"] in ("dev.dispatch", "dev.join") and d["ts"] <= e["ts"]
                    and e["ts"] + e["dur"] <= d["ts"] + d["dur"]
                    for d in by_tid[e["tid"]]), e
 

@@ -124,6 +124,30 @@ def test_group_slice_quantize_fits_in_bounded_memory(chip, fmt):
     assert total < 2 * 10**9, total
 
 
+@pytest.mark.parametrize("placement", ["resident", "uploaded"])
+def test_group_slice_join_fits_in_bounded_memory(chip, placement):
+    """A fused quantize group's slice is assembled on the device: here
+    the third slice of a group that opens with the embedding and goes
+    on into qwen's stacked MLP weight (24 x 1024 x 2816), taken from the
+    whole device arrays (an uplink) or from the uploaded pieces (a
+    downlink). Its output and scratch stay O(slice)."""
+    from repro.core.quantization import GROUP_SLICE_ELEMS, _join_slice
+
+    lo = 2 * GROUP_SLICE_ELEMS
+    pad = -N_EMBED % ops.BLOCK8
+    at = N_EMBED + pad - lo
+    n_mlp = GROUP_SLICE_ELEMS - at
+    if placement == "resident":
+        pieces = (chip((VOCAB, D_MODEL), jnp.float32), chip((24, D_MODEL, 2816), jnp.float32))
+        cuts = ((0, lo, N_EMBED), (at, 0, n_mlp))
+    else:
+        pieces = (chip((N_EMBED - lo,), jnp.float32), chip((n_mlp,), jnp.float32))
+        cuts = ((0, 0, N_EMBED - lo), (at, 0, n_mlp))
+    ma = _join_slice.lower(pieces, cuts, GROUP_SLICE_ELEMS).compile().memory_analysis()
+    assert ma.output_size_in_bytes == 4 * GROUP_SLICE_ELEMS
+    assert ma.output_size_in_bytes + ma.temp_size_in_bytes < 4 * 4 * GROUP_SLICE_ELEMS
+
+
 @pytest.mark.parametrize("mode", ["forward", "value_and_grad"])
 def test_flash_attention_compiles_for_v5e(chip, mode):
     from repro.kernels.flash_attention import flash_attention_pallas
