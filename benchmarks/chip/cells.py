@@ -13,6 +13,8 @@
 * ``limits/<workload>.json``: the numbers ``correct`` compares, each
   with its limit and the readings that set it.
 * ``metrics/<metric>.py``: one reader per per-layer metric.
+* ``refmodels/<reference>.py``: one reference module per architecture,
+  named by the configuration (``refmodel.model_ref``).
 
 Nothing here imports JAX or the program.
 """
@@ -21,6 +23,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+import types
 from typing import Any, Callable
 
 import numpy as np
@@ -72,13 +75,18 @@ def load_peaks(device_kind: str) -> dict:
     return table["devices"][device_kind]
 
 
+def load_module(path: str, modname: str) -> types.ModuleType:
+    """The Python file at ``path``, loaded as a module named ``modname``."""
+    spec = importlib.util.spec_from_file_location(modname, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str) -> Callable[[Any], Any]:
     """``metrics/<name>.py``'s ``read(ctx)``, loaded by file path."""
     path = os.path.join(HERE, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(path, f"chipbench_metric_{name}").read
 
 
 def end_to_end_metrics(bench: dict, workload: str) -> list[dict]:

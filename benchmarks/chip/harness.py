@@ -58,7 +58,10 @@ def register_model(config: dict) -> str:
     from repro.models.base import ModelConfig
 
     fields = {f.name for f in dataclasses.fields(ModelConfig)}
-    cfg = ModelConfig(**cells.model_fields(config, fields))
+    # JSON has lists where the config has tuples (``block_pattern``); a
+    # frozen config passed as a static argument must hash
+    cfg = ModelConfig(**{k: tuple(v) if isinstance(v, list) else v
+                         for k, v in cells.model_fields(config, fields).items()})
     modname = "chipbench_" + re.sub(r"[^0-9A-Za-z]", "_", cfg.arch_id)
     mod = types.ModuleType(f"repro.configs.{modname}")
     mod.CONFIG = cfg
@@ -352,16 +355,17 @@ def _reduce_trace(trace_dir: str, spans: list[dict]) -> dict:
 
 def _reader_context(cell: Cell, summary: dict, spans: list[dict], counter: Any,
                     peaks: Optional[dict], window_s: float) -> Any:
-    import flopcount
     import tracereduce
+    from refmodel import model_ref
 
     events, rounds = tracereduce.window_events(spans, first_round=1)
     t = cell.traffic
+    ref = model_ref(cell.config)
     return types.SimpleNamespace(
         cell=cell.cell, traffic=t, config=cell.config,
         trace=summary, spans=events, rounds=rounds, window_s=window_s,
         steps=rounds * t["clients"] * t["local_steps"],
-        step_flops=flopcount.step_flops(cell.config, t["batch"], t["seq"]),
+        step_flops=ref.step_flops(cell.config, t["batch"], t["seq"]),
         kernel_elems=dict(counter.elems),
         peaks=peaks,
     )

@@ -1,18 +1,21 @@
 """Plain reference of one federation round, and the cell's weights.
 
 Nothing here imports the system under test. The round is written from
-the configuration file and the traffic file alone:
+the configuration file and the traffic file alone, and depends on no
+architecture: the model (its leaves, loss, FLOPs and CPU cut) is the
+reference module of the configuration's ``family``,
+``refmodels/<family>.py``, which :func:`model_ref` loads by file path
+(see ``refmodels/dense.py`` for the names each module exports).
 
-* weights: every leaf drawn from the seed in one jitted call on the
-  device (normal times the leaf's init scale; norms and biases zero),
-  float32, the dtype the configuration trains in;
+* weights: every leaf of the module's ``leaf_specs`` drawn from the
+  seed in one jitted call on the device (normal times the leaf's init
+  scale; std 0 means zeros), float32, the dtype the configuration
+  trains in;
 * downlink: each leaf through the hop's codec and back (blockwise8:
   symmetric int8 over absmax blocks of 4096; nf4: the QLoRA codebook
   over absmax blocks of 64, nearest entry; no stage: unchanged);
-* each client: its local AdamW steps on its own token rows, the model a
-  dense pre-norm decoder (RMSNorm, rotary attention with optional QKV
-  bias, SwiGLU, untied head, causal LM loss with z-loss) in
-  ``jax.numpy`` at ``highest`` matmul precision;
+* each client: its local AdamW steps on its own token rows, on the
+  module's ``loss_fn``;
 * uplink: the trained leaves through the hop's codec and back, folded
   on the host in float32 as the sample-weighted mean.
 
@@ -25,6 +28,8 @@ from __future__ import annotations
 
 import functools
 import math
+import os
+import types
 from collections.abc import Callable
 from typing import Any, Optional
 
@@ -32,7 +37,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-HIGHEST = jax.lax.Precision.HIGHEST
+import cells
+
+#: one reference module per architecture, found by the configuration's
+#: ``"family"`` (the program's own architecture field)
+REFMODELS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "refmodels")
+#: the names every reference module exports
+INTERFACE = ("leaf_specs", "loss_fn", "step_flops", "tiny")
 
 BLOCK8 = 4096
 BLOCK4 = 64
@@ -56,35 +67,24 @@ FAULTS = ("stale", "half_batch", "one_client", "codec_bypass")
 # leaves and weights
 # ---------------------------------------------------------------------------
 
-def head_dim(model: dict) -> int:
-    return int(model.get("head_dim") or model["d_model"] // model["num_heads"])
+def model_ref(config: dict) -> types.ModuleType:
+    """The reference module of the configuration's ``"family"``,
+    loaded once from ``refmodels/<family>.py`` by file path."""
+    return _load_ref(REFMODELS, config.get("family"))
 
 
-def leaf_specs(model: dict) -> dict[str, tuple[tuple[int, ...], float]]:
-    """Flat leaf name -> (shape, init std); std 0 means zeros."""
-    L, d, f, V = (model[k] for k in ("num_layers", "d_model", "d_ff", "vocab_size"))
-    qf = model["num_heads"] * head_dim(model)
-    kvf = model["num_kv_heads"] * head_dim(model)
-    std, emb_std = float(model["init_std"]), float(model["embed_init_std"])
-    out = {
-        "blocks.attn.wq": ((L, d, qf), std),
-        "blocks.attn.wk": ((L, d, kvf), std),
-        "blocks.attn.wv": ((L, d, kvf), std),
-        "blocks.attn.wo": ((L, qf, d), std),
-        "blocks.attn_norm": ((L, d), 0.0),
-        "blocks.mlp.w_gate": ((L, d, f), std),
-        "blocks.mlp.w_up": ((L, d, f), std),
-        "blocks.mlp.w_down": ((L, f, d), std),
-        "blocks.mlp_norm": ((L, d), 0.0),
-        "embed.embedding": ((V, d), emb_std),
-        "embed.final_norm": ((d,), 0.0),
-        "embed.lm_head": ((d, V), std),
-    }
-    if model.get("qkv_bias"):
-        out["blocks.attn.bq"] = ((L, qf), 0.0)
-        out["blocks.attn.bk"] = ((L, kvf), 0.0)
-        out["blocks.attn.bv"] = ((L, kvf), 0.0)
-    return dict(sorted(out.items()))
+@functools.lru_cache(maxsize=None)
+def _load_ref(directory: str, name: Optional[str]) -> types.ModuleType:
+    known = sorted(f[:-3] for f in os.listdir(directory)
+                   if f.endswith(".py") and not f.startswith("_"))
+    if name not in known:
+        raise KeyError(f"family {name!r} has no reference module; "
+                       f"known reference modules: {known}")
+    mod = cells.load_module(os.path.join(directory, f"{name}.py"), f"chipbench_refmodel_{name}")
+    missing = [n for n in INTERFACE if not callable(getattr(mod, n, None))]
+    if missing:
+        raise AttributeError(f"reference module {name!r} does not export {missing}")
+    return mod
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -96,7 +96,7 @@ def seed_key(seed: int) -> jax.Array:
 
 def init_weights(model: dict, seed: int) -> dict[str, jax.Array]:
     """All leaves from ``seed`` in one jitted call, on the device, float32."""
-    specs = leaf_specs(model)
+    specs = model_ref(model).leaf_specs(model)
 
     @jax.jit
     def make(key):
@@ -149,74 +149,15 @@ def codec_roundtrip(x: jax.Array, fmt: Optional[str]) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# model and local step
+# local step
 # ---------------------------------------------------------------------------
 
-def _rms(x, w, eps):
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * (1.0 + w)
-
-
-def _rope(x, theta):
-    """x: (b, s, heads, hd); rotates the two halves of each head."""
-    s, hd = x.shape[1], x.shape[3]
-    half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs
-    c = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
-    sn = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * c - x2 * sn, x2 * c + x1 * sn], axis=-1)
-
-
-def loss_fn(params: dict, tokens: jax.Array, model: dict, dtype) -> jax.Array:
-    """Mean causal-LM loss (+ z-loss) of ``tokens`` (labels = tokens)."""
-    p = {k: v.astype(dtype) for k, v in params.items()}
-    eps = float(model["rms_norm_eps"])
-    H, KV, hd = model["num_heads"], model["num_kv_heads"], head_dim(model)
-    theta = float(model["rope_theta"])
-    mm = functools.partial(jnp.einsum, precision=HIGHEST)
-    blocks = {k[len("blocks."):]: v for k, v in p.items() if k.startswith("blocks.")}
-
-    def layer(x, bp):
-        b, s, _ = x.shape
-        h = _rms(x, bp["attn_norm"][None, None], eps)
-        q = mm("bsd,df->bsf", h, bp["attn.wq"])
-        k = mm("bsd,df->bsf", h, bp["attn.wk"])
-        v = mm("bsd,df->bsf", h, bp["attn.wv"])
-        if "attn.bq" in bp:
-            q, k, v = q + bp["attn.bq"], k + bp["attn.bk"], v + bp["attn.bv"]
-        q = _rope(q.reshape(b, s, H, hd), theta)
-        k = _rope(k.reshape(b, s, KV, hd), theta)
-        v = v.reshape(b, s, KV, hd)
-        if KV != H:
-            k, v = jnp.repeat(k, H // KV, axis=2), jnp.repeat(v, H // KV, axis=2)
-        scores = mm("bshe,bthe->bhst", q, k) / jnp.asarray(math.sqrt(hd), dtype)
-        causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
-        scores = jnp.where(causal, scores, jnp.asarray(-1e30, dtype))
-        probs = jax.nn.softmax(scores, axis=-1)
-        o = mm("bhst,bthe->bshe", probs, v).reshape(b, s, H * hd)
-        x = x + mm("bsf,fd->bsd", o, bp["attn.wo"])
-        h = _rms(x, bp["mlp_norm"][None, None], eps)
-        g = mm("bsd,df->bsf", h, bp["mlp.w_gate"])
-        u = mm("bsd,df->bsf", h, bp["mlp.w_up"])
-        return x + mm("bsf,fd->bsd", jax.nn.silu(g) * u, bp["mlp.w_down"]), None
-
-    x = p["embed.embedding"][tokens]
-    x, _ = jax.lax.scan(jax.checkpoint(layer), x, blocks)
-    x = _rms(x, p["embed.final_norm"], eps)
-    logits = mm("bsd,dv->bsv", x, p["embed.lm_head"])[:, :-1]
-    labels = tokens[:, 1:]
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    nll = lse - gold + jnp.asarray(float(model["z_loss"]), dtype) * jnp.square(lse)
-    return jnp.mean(nll)
-
-
 def make_local_step(model: dict, opt: dict, dtype) -> Callable:
-    """One AdamW step (global-norm clip, decoupled weight decay, bias
-    correction) on state ``(p, m, v)`` held in ``dtype``; returns the
-    new state, the loss and each leaf's gradient norm before clipping."""
+    """One AdamW step on the model's loss (global-norm clip, decoupled
+    weight decay, bias correction) on state ``(p, m, v)`` held in
+    ``dtype``; returns the new state, the loss and each leaf's gradient
+    norm before clipping."""
+    loss_fn = model_ref(model).loss_fn
     b1, b2, eps = float(opt["b1"]), float(opt["b2"]), float(opt["eps"])
     wd, lr, clip = float(opt["weight_decay"]), float(opt["lr"]), float(opt["clip_norm"])
 

@@ -16,6 +16,7 @@ sys.path.insert(0, HERE)
 
 import cells  # noqa: E402
 import flopcount  # noqa: E402
+import refmodel  # noqa: E402
 
 BENCH = cells.load_benchmark(ROOT)
 WORKLOADS = [c["name"] for c in BENCH["workloads"]]
@@ -59,15 +60,17 @@ def test_peaks_table():
 ])
 def test_parameter_counts(config, params):
     model = cells.load_config(ROOT, BENCH, config)
-    assert flopcount.param_count(model) == (params, 15)
+    specs = refmodel.model_ref(model).leaf_specs(model)
+    assert (sum(math.prod(shape) for shape, _ in specs.values()), len(specs)) == (params, 15)
 
 
 def test_step_flops_by_hand():
     model = cells.load_config(ROOT, BENCH, "qwen1.5-0.5b")
     # 24 x (4 x 1024^2 + 3 x 1024 x 2816) + 1024 x 151936
-    assert flopcount.matmul_params(model) == 463_863_808
+    dense = refmodel.model_ref(model)
+    assert dense.matmul_params(model) == 463_863_808
     per_token = 6 * 463_863_808 + 12 * 24 * 512 * 1024
-    assert flopcount.step_flops(model, 4, 512) == per_token * 2048
+    assert dense.step_flops(model, 4, 512) == per_token * 2048
 
 
 @pytest.mark.parametrize("kind,elems,nbytes", [

@@ -1,8 +1,9 @@
 """A cell of the benchmark cut to a size a CPU test run can hold.
 
 The configuration, traffic mix and limits are the cell's own files;
-only the widths, depth, vocabulary and sequence are shrunk, so every
-layer and every wire and fold path of the cell still runs.
+only the sizes that the configuration's reference module cuts
+(``tiny(model)``) and the sequence are shrunk, so every layer and every
+wire and fold path of the cell still runs.
 """
 from __future__ import annotations
 
@@ -17,15 +18,15 @@ if HERE not in sys.path:
 
 import cells  # noqa: E402
 import harness  # noqa: E402
+from refmodel import model_ref  # noqa: E402
 
-TINY = {"num_layers": 2, "d_model": 128, "num_heads": 4, "num_kv_heads": 4,
-        "d_ff": 256, "vocab_size": 4096}
 SEQ = 64
 
 
 def tiny_cell(workload: str) -> harness.Cell:
     cell = harness.Cell.load(ROOT, workload)
-    cell.config = {**cell.config, **TINY, "arch_id": f"chipbench.tiny.{workload}"}
+    cut = model_ref(cell.config).tiny(cell.config)
+    cell.config = {**cell.config, **cut, "arch_id": f"chipbench.tiny.{workload}"}
     cell.traffic = {**cell.traffic, "seq": SEQ}
     return cell
 
