@@ -142,8 +142,17 @@ def test_stub_reference_module_is_used_end_to_end(tmp_path, monkeypatch):
     assert _tree_digest(*bench_files) == before
 
 
-@pytest.mark.parametrize("workload", WORKLOADS)
+@pytest.mark.parametrize("workload", sorted(PINNED_LOSSES))
+def test_pinned_cell_is_a_dense_workload(workload):
+    """A pin cannot outlive its cell, or its cell's dense decoder."""
+    cell = cells.find_cell(BENCH, workload)
+    assert cells.load_config(ROOT, BENCH, cell["config"])["family"] == "dense"
+
+
+@pytest.mark.parametrize("workload", sorted(PINNED_LOSSES))
 def test_dense_reference_reads_the_pinned_round(workload):
+    """Only the cells pinned: a cell added later has leaves and losses
+    of its own."""
     cell = tinycell.tiny_cell(workload)
     w0 = harness.host_weights(cell.config, PIN_SEED)
     h = hashlib.sha256()
