@@ -150,7 +150,10 @@ class FedAvgAggregator(Aggregator):
         with self._lock, obs_trace.span("host.fold", "host", nbytes=arr.nbytes):
             acc = self._sum.get(name)
             if acc is None:
-                self._sum[name] = arr * np.float32(weight)
+                # an array even at 0-d (a ufunc returns a NumPy scalar
+                # there), so later folds and finish() work in place
+                self._sum[name] = np.multiply(arr, np.float32(weight),
+                                              out=np.empty(arr.shape, np.float32))
                 return
             scratch = self._scratch.get(arr.shape)
             if scratch is None:
@@ -163,12 +166,13 @@ class FedAvgAggregator(Aggregator):
         with obs_trace.span("agg.finish", "agg"), self._lock:
             if self._weight <= 0:
                 raise RuntimeError("no results accepted")
+            # the running sums are this aggregator's own: normalise them
+            # in place and hand them out, one pass and no second copy
+            out, w = self._sum, np.float32(self._weight)
             with obs_trace.span("host.fold", "host",
-                                nbytes=sum(a.nbytes for a in self._sum.values())):
-                out = {
-                    name: (arr / self._weight).astype(np.float32)
-                    for name, arr in self._sum.items()
-                }
+                                nbytes=sum(a.nbytes for a in out.values())):
+                for arr in out.values():
+                    np.divide(arr, w, out=arr)
             self._sum = {}
             self._weight = 0.0
             self.accepted = 0
@@ -243,21 +247,21 @@ class QuantizedFedAvgAggregator(Aggregator):
 
     def finish(self) -> dict[str, np.ndarray]:
         with obs_trace.span("agg.finish", "agg"), self._lock:
-            # the fold's single sync point: every accept_item dispatch so
-            # far was async (the donated fold kernel queues on XLA's own
-            # threadpool while the receiver assembles the next item); one
-            # barrier here beats a device round trip per tensor below
-            ops.block_until_ready(list(self._acc.values()))
             out: dict[str, np.ndarray] = {}
-            inv = np.float32(1.0) / np.float32(self._weight if self._weight else 1.0)
-            for name, acc in self._acc.items():
-                shape = self._shape[name]
-                n = int(np.prod(shape))
-                host = ops.to_host(acc)
-                with obs_trace.span("host.fold", "host", nbytes=4 * n):
-                    out[name] = (
-                        host.reshape(-1)[:n].reshape(shape) * inv
-                    ).astype(np.float32)
+            if self._acc:
+                # one donated dispatch scales every accumulator where it
+                # lives; each then comes down once, its crop a view (the
+                # host copies are read-only, and nothing more is made)
+                inv = np.float32(1.0) / np.float32(self._weight if self._weight else 1.0)
+                names = list(self._acc)
+                scaled = ops.scale_into([self._acc[n] for n in names], inv)
+                # the fold's single sync point: every dispatch so far was
+                # async; one barrier beats a round trip per tensor below
+                ops.block_until_ready(scaled)
+                for name, acc in zip(names, scaled):
+                    shape = self._shape[name]
+                    n = int(np.prod(shape))
+                    out[name] = ops.to_host(acc).reshape(-1)[:n].reshape(shape)
             if self._plain_names:
                 # reuse the plain aggregator's running sum (shares self._weight)
                 self._plain._weight = self._weight

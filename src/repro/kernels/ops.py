@@ -21,7 +21,8 @@ boundary here, around the same calls an untraced run makes: the codec
 and fold entry points open ``dev.dispatch`` (``kind``, ``elems``) and,
 when NumPy arguments ride up with the dispatch, ``host.h2d`` inside it
 (``nbytes``: the host's hold of that dispatch, the copy's synchronous
-part); :func:`block_until_ready` opens ``dev.sync``; :func:`to_host` is
+part); :func:`scale_into` opens ``dev.scale`` (``elems``);
+:func:`block_until_ready` opens ``dev.sync``; :func:`to_host` is
 ``np.asarray`` with a device array's wait (``dev.sync``) and copy
 (``host.d2h``, ``nbytes``) timed apart.
 """
@@ -368,6 +369,25 @@ def dequant_accumulate8_into(
             acc, q, absmax, jnp.float32(weight),
             interpret=(backend == "pallas_interpret"),
         )
+
+
+# the aggregate's normalisation: every accumulator times one scalar, as
+# one program over the tuple, the tuple donated so the scale is in place
+_SCALE = jax.jit(lambda accs, s: tuple(a * s for a in accs), donate_argnums=(0,))
+
+
+def scale_into(accs, s: np.float32) -> tuple[jnp.ndarray, ...]:
+    """``tuple(a * s for a in accs)`` in one dispatch, on the device.
+
+    ``accs`` is **donated**: each result reuses its accumulator's buffer,
+    so normalising a round's running sums allocates no device memory and
+    leaves the inputs deleted. ``s`` is traced (a float32 scalar), so no
+    weight recompiles the program; one program per tuple of shapes.
+    Traced, the dispatch is ``dev.scale`` (``elems``): not a codec
+    ``dev.dispatch``, whose kinds are the codec and fold entry points."""
+    accs = tuple(accs)
+    with obs_trace.span("dev.scale", "dev", elems=int(sum(a.size for a in accs))):
+        return _SCALE(accs, np.float32(s))
 
 
 # ---------------------------------------------------------------------------

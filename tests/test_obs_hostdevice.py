@@ -37,6 +37,13 @@ PATHS = {
 }
 
 
+def _opened(path: str) -> set[str]:
+    """The spans of ``SPANS`` a round of the path opens: blockwise8
+    folds and normalises on the device, so only nf4 does NumPy
+    arithmetic (``host.fold``)."""
+    return set(SPANS) - ({"host.fold"} if path == "qwen05b-b8-stream" else set())
+
+
 def _spec(path: str, trace: bool) -> dict:
     p = PATHS[path]
     return {"arch": p["arch"], "smoke": True, "seed": 7, "rounds": 2,
@@ -115,13 +122,37 @@ def _expected_elems(path: str, weights: dict) -> dict[str, int]:
 
 
 def test_round_spans_inside_the_window(runs):
-    _path, traced, _ = runs
+    path, traced, _ = runs
     assert traced["dropped"] == 0
     names = {e["name"] for e in traced["spans"]}
-    assert set(SPANS) <= names, set(SPANS) - names
+    want = _opened(path)
+    assert want <= names, want - names
     assert not {n for n in names if n.startswith(("kernel.", "agg."))} - {
         "kernel.quantize_batch", "kernel.dequantize_batch",
         "kernel.dequant_accumulate8", "agg.begin", "agg.accept_item", "agg.finish"}
+
+
+def test_finish_scales_on_the_device_path(runs):
+    """blockwise8's ``finish`` scales every accumulator (whole blocks)
+    in one ``dev.scale`` dispatch and copies each sum down once: it
+    opens no ``host.fold``. Only nf4's dense fold does NumPy arithmetic
+    there, and it dispatches no scale."""
+    path, traced, _ = runs
+    spans = traced["spans"]
+    (finish,) = [e for e in spans if e["name"] == "agg.finish"]
+
+    def inside(name):
+        return [e for e in spans if e["name"] == name and e["tid"] == finish["tid"]
+                and finish["ts"] <= e["ts"]
+                and e["ts"] + e["dur"] <= finish["ts"] + finish["dur"]]
+
+    scales = [e["args"]["elems"] for e in spans if e["name"] == "dev.scale"]
+    if path == "qwen05b-b8-stream":
+        _, block, blocks, _ = _layout(path, traced["weights"])
+        assert not inside("host.fold")
+        assert scales == [block * blocks] and len(inside("dev.scale")) == 1
+    else:
+        assert inside("host.fold") and not scales
 
 
 def test_dispatch_elements_follow_the_parameters(runs):
@@ -176,4 +207,5 @@ def test_profiler_trace_holds_the_program_spans(tmp_path):
     host = {e.name for plane in ProfileData.from_file(path).planes
             if plane.name.startswith("/host:")
             for line in plane.lines for e in line.events}
-    assert {"round", "wire.transmit", "kernel.quantize_batch", *SPANS} <= host
+    assert {"round", "wire.transmit", "kernel.quantize_batch",
+            *_opened("qwen05b-b8-stream")} <= host
